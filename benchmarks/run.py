@@ -44,6 +44,8 @@ def main() -> None:
                          "(launches, resident edges, kernel ids) as "
                          "JSON — CI uploads it as an artifact")
     args = ap.parse_args()
+    from repro.runtime import compile_cache
+    compile_cache.configure()
 
     sections = {
         "fusion": fusion_bench.run,

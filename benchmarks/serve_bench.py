@@ -315,6 +315,8 @@ def main(argv=None) -> int:
     ap.add_argument("--report", default=None,
                     help="write the full ServeReport JSON")
     args = ap.parse_args(argv)
+    from repro.runtime import compile_cache
+    compile_cache.configure()
 
     if args.faults in ("chaos", "heal"):
         out = (chaos if args.faults == "chaos" else heal)(args.preset)

@@ -149,15 +149,48 @@ def _block_shape(vt, item_shape, dims, grid_axes) -> Tuple[int, ...]:
                  for d, b in _axes(vt, item_shape))
 
 
+def _row(shape: Sequence[int]) -> Tuple[int, ...]:
+    """The shape a merged array has at the ``pallas_call`` boundary.
+    Mosaic tiles a rank-1 operand by 128 lanes while XLA may tile it by
+    its whole length, and refuses the mismatch; so rank-1 arrays (the
+    position vectors, per-row statistics) cross as one ``(1, n)`` row,
+    and the TPU's (8, 128)-or-whole block rule applies to them as to
+    any matrix."""
+    return (1, *shape) if len(shape) == 1 else tuple(shape)
+
+
 def _block_spec(vt, item_shape, dims, grid_axes) -> pl.BlockSpec:
     axes = _axes(vt, item_shape)
     shape = _block_shape(vt, item_shape, dims, grid_axes)
 
     def index_map(*gids, axes=tuple(axes)):
         pos = dict(zip(grid_axes, gids))
-        return tuple(pos[d] if d in grid_axes else 0 for d, _ in axes)
+        idx = tuple(pos[d] if d in grid_axes else 0 for d, _ in axes)
+        return (0, *idx) if len(idx) == 1 else idx
 
-    return pl.BlockSpec(shape, index_map)
+    return pl.BlockSpec(_row(shape), index_map)
+
+
+def _call_rows(kernel, merged_inputs, *, grid, in_specs, out_specs,
+               out_full, scratch, donate, in_layouts, out_layouts,
+               interpret):
+    """``pallas_call`` over merged arrays, with rank-1 operands passed
+    as ``(1, n)`` rows (:func:`_row`) and returned in their merged
+    shapes."""
+    dtype = (jnp.result_type(*merged_inputs) if merged_inputs
+             else jnp.float32)
+    outs = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=[jax.ShapeDtypeStruct(_row(s), dtype) for s in out_full],
+        scratch_shapes=scratch,
+        input_output_aliases=_alias_map(merged_inputs, out_full, dtype,
+                                        donate, in_layouts, out_layouts),
+        interpret=interpret,
+    )(*[a.reshape(_row(a.shape)) for a in merged_inputs])
+    return tuple(o.reshape(s) for o, s in zip(outs, out_full))
 
 
 def _split_whole(arr, vt_dims, dims, grid_axes, axis=0):
@@ -541,6 +574,7 @@ def emit_region(spec: RegionSpec, dims: Dict[str, int],
 
     n_in, n_out = len(rg.input_ids), len(slots)
     n_red = dims[red_dim] if red_dim else 0
+    in_blocks = [a.shape for a in abstract_ins]
 
     def write(o_ref, slot, ish, v):
         merged = _merge_value(v, slot.vt, len(ish), dims, grid_axes)
@@ -550,9 +584,11 @@ def emit_region(spec: RegionSpec, dims: Dict[str, int],
         in_refs = refs[:n_in]
         out_refs = refs[n_in:n_in + n_out]
         acc_refs = refs[n_in + n_out:]
-        values = {iid: _split_value(r[...], vt, ish, dims, grid_axes)
-                  for iid, r, vt, ish in zip(rg.input_ids, in_refs,
-                                             in_types, in_item_shapes)}
+        values = {iid: _split_value(r[...].reshape(blk), vt, ish, dims,
+                                    grid_axes)
+                  for iid, r, blk, vt, ish in zip(rg.input_ids, in_refs,
+                                                  in_blocks, in_types,
+                                                  in_item_shapes)}
         if acc_id is None:
             envs = final_envs(values, [])
             for o_ref, slot, ish in zip(out_refs, slots, out_item_shapes):
@@ -611,21 +647,11 @@ def emit_region(spec: RegionSpec, dims: Dict[str, int],
                    for s, ish in zip(slots, out_item_shapes)]
 
     def region_fn(*merged_inputs):
-        dtype = (jnp.result_type(*merged_inputs) if merged_inputs
-                 else jnp.float32)
-        outs = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=[jax.ShapeDtypeStruct(s, dtype) for s in out_full],
-            scratch_shapes=scratch,
-            input_output_aliases=_alias_map(merged_inputs, out_full,
-                                            dtype, donate, in_layouts,
-                                            out_layouts),
-            interpret=interpret,
-        )(*merged_inputs)
-        return tuple(outs)
+        return _call_rows(kernel, merged_inputs, grid=grid,
+                          in_specs=in_specs, out_specs=out_specs,
+                          out_full=out_full, scratch=scratch,
+                          donate=donate, in_layouts=in_layouts,
+                          out_layouts=out_layouts, interpret=interpret)
 
     report = RegionReport(spec.label, tuple(grid_dims), red_dim, n_out)
     return region_fn, out_item_shapes, report
@@ -687,12 +713,15 @@ def emit_group(group, types: Dict[Ref, VType], dims: Dict[str, int],
     in_specs = [_block_spec(vt, ish, dims, grid_axes)
                 for vt, ish in zip(in_types, in_item_shapes)]
     n_in, n_out = len(group.in_refs), len(group.out_refs)
+    in_blocks = [a.shape for a in abstract_ins]
 
     def kernel(*refs):
         in_refs_, out_refs_ = refs[:n_in], refs[n_in:n_in + n_out]
-        values = {r: _split_value(ref[...], vt, ish, dims, grid_axes)
-                  for r, ref, vt, ish in zip(group.in_refs, in_refs_,
-                                             in_types, in_item_shapes)}
+        values = {r: _split_value(ref[...].reshape(blk), vt, ish, dims,
+                                  grid_axes)
+                  for r, ref, blk, vt, ish in zip(group.in_refs, in_refs_,
+                                                  in_blocks, in_types,
+                                                  in_item_shapes)}
         env = run_stages(values)
         for o_ref, r, vt, ish in zip(out_refs_, group.out_refs,
                                      out_types, out_item_shapes):
@@ -707,20 +736,11 @@ def emit_group(group, types: Dict[Ref, VType], dims: Dict[str, int],
                    for vt, ish in zip(out_types, out_item_shapes)]
 
     def group_fn(*merged_inputs):
-        dtype = (jnp.result_type(*merged_inputs) if merged_inputs
-                 else jnp.float32)
-        outs = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=[jax.ShapeDtypeStruct(s, dtype) for s in out_full],
-            input_output_aliases=_alias_map(merged_inputs, out_full,
-                                            dtype, donate, in_layouts,
-                                            out_layouts),
-            interpret=interpret,
-        )(*merged_inputs)
-        return tuple(outs)
+        return _call_rows(kernel, merged_inputs, grid=grid,
+                          in_specs=in_specs, out_specs=out_specs,
+                          out_full=out_full, scratch=(), donate=donate,
+                          in_layouts=in_layouts, out_layouts=out_layouts,
+                          interpret=interpret)
 
     reports = [RegionReport(spec.label, spec.grid_dims, spec.red_dim,
                             len(spec.out_refs), group=group.gid)

@@ -28,6 +28,7 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 from repro.launch.engine import Engine, ServeReport, synth_trace
+from repro.runtime import compile_cache
 
 
 @dataclass(frozen=True)
@@ -42,6 +43,9 @@ class ServeConfig:
     max_len: int = 96
     prompt_buckets: Tuple[int, ...] = (8, 16, 32)
     max_queue: Optional[int] = None  # bounded admission; None = unbounded
+    # deepest compile-ladder rung a kernel may demote to; "grouped"
+    # permits none, so a lowering failure raises with its own message
+    max_rung: str = "interpreter"
     # -- synthetic open-loop trace -------------------------------------------
     n_requests: int = 16
     arrival_rate: float = 1.0    # requests per engine step
@@ -66,9 +70,11 @@ def build_engine(cfg: ServeConfig) -> Engine:
     ``warmup()`` or let ``Engine.run`` do it)."""
     import jax.numpy as jnp
 
-    from repro import configs, pipeline
+    from repro import configs, pipeline, resilience
 
-    options = pipeline.CompileOptions(backend=cfg.backend)
+    options = pipeline.CompileOptions(
+        backend=cfg.backend,
+        resilience=resilience.ResiliencePolicy(max_rung=cfg.max_rung))
     mc = (configs.get_reduced_config(cfg.arch)
           if cfg.reduced else configs.get_config(cfg.arch))
     mc = dataclasses.replace(mc, dtype=getattr(jnp, cfg.dtype))
@@ -82,16 +88,20 @@ def build_engine(cfg: ServeConfig) -> Engine:
                   repromote_after=cfg.repromote_after)
 
 
-def run(cfg: ServeConfig) -> ServeReport:
-    """Build the engine, warm the kernel set, replay the trace."""
-    engine = build_engine(cfg)
-    trace = synth_trace(cfg.n_requests, seed=cfg.seed,
-                        arrival_rate=cfg.arrival_rate,
-                        prompt_lens=cfg.prompt_lens,
-                        gen_lens=cfg.gen_lens,
-                        vocab=engine.cfg.vocab)
-    engine.warmup()
-    return engine.run(trace, max_steps=cfg.max_steps)
+def trace_of(cfg: ServeConfig, vocab: int):
+    """The configured synthetic open-loop trace."""
+    return synth_trace(cfg.n_requests, seed=cfg.seed,
+                       arrival_rate=cfg.arrival_rate,
+                       prompt_lens=cfg.prompt_lens, gen_lens=cfg.gen_lens,
+                       vocab=vocab)
+
+
+def run(cfg: ServeConfig, engine: Optional[Engine] = None) -> ServeReport:
+    """Build the engine (unless given one), warm the kernel set unless it
+    is warm already, and replay the trace."""
+    engine = engine if engine is not None else build_engine(cfg)
+    return engine.run(trace_of(cfg, engine.cfg.vocab),
+                      max_steps=cfg.max_steps)
 
 
 def main(argv=None) -> ServeReport:
@@ -124,6 +134,7 @@ def main(argv=None) -> ServeReport:
     ap.add_argument("--json", dest="json_out", default=None,
                     help="write the full ServeReport as JSON")
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     cfg = ServeConfig(arch=args.arch, reduced=not args.full,
                       backend=args.backend, max_batch=args.max_batch,

@@ -34,17 +34,17 @@ from repro.runtime.sharding import constrain
 # "pipeline"; ``cfg.pipeline_backend`` picks the codegen backend.
 # ---------------------------------------------------------------------------
 
-def _n_blocks(size: int, target: int = 128) -> int:
-    """Smallest block count that divides ``size`` evenly with blocks at
-    most ``target`` wide.  When only pathologically thin blocks would
-    qualify (no divisor yields a block within target/4..target — e.g. a
-    prime size), keep the dim whole instead of shattering it."""
-    cnt = max(1, -(-size // target))
-    while size % cnt:
-        cnt += 1
-    if cnt > 1 and (size // cnt) * 4 < target:
-        return 1
-    return cnt
+LANES = 128  # TPU vector lane width: the block quantum of every layer dim
+
+
+def _n_blocks(size: int) -> int:
+    """Block count for one layer dim under the TPU tiling rule: the last
+    two axes of every block are multiples of (8, 128) or span the whole
+    array.  A layer dim can be the last axis of one operand (``D`` of
+    ``X``) and the second-to-last of another (``D`` of ``UT``), so each
+    dim is cut into 128-wide blocks when 128 divides it and otherwise
+    kept whole (smollm's d_model 576 = 4.5 x 128, a 64-wide head)."""
+    return size // LANES if size % LANES == 0 else 1
 
 
 def _pipeline_dims_blocks(sizes):
@@ -543,7 +543,6 @@ def _moe_apply_sharded(p, x, gamma, cfg: ModelConfig, mesh) -> jax.Array:
          experts' rows + psum over model (bf16 partials).
     """
     import math
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -578,11 +577,11 @@ def _moe_apply_sharded(p, x, gamma, cfg: ModelConfig, mesh) -> jax.Array:
         buf = buf.at[slot.reshape(-1)].add(upd)
         return buf.reshape(e, cap_local, d), slot, keep
 
-    eb, slot, keep = shard_map(
+    eb, slot, keep = jax.shard_map(
         local_dispatch, mesh=mesh,
         in_specs=(P(dax), P(dax)),
         out_specs=(P(None, dax, None), P(dax), P(dax)),
-        check_rep=False,
+        check_vma=False,
     )(xn, top_ids)
     eb = constrain(eb, "expert", "capacity", None)
 
@@ -606,11 +605,11 @@ def _moe_apply_sharded(p, x, gamma, cfg: ModelConfig, mesh) -> jax.Array:
         out = jnp.einsum("tkd,tk->td", routed, w_l.astype(routed.dtype))
         return jax.lax.psum(out.astype(jnp.bfloat16), "model")
 
-    out = shard_map(
+    out = jax.shard_map(
         local_combine, mesh=mesh,
         in_specs=(P("model", dax, None), P(dax), P(dax), P(dax)),
         out_specs=P(dax),
-        check_rep=False,
+        check_vma=False,
     )(eo, slot, keep, top_w)
 
     if cfg.n_shared_experts:

@@ -59,11 +59,10 @@ def compressed_psum_mean(grads, mesh, axes=("data",), errors=None):
                                is_leaf=lambda x: isinstance(x, tuple))
         return synced, new_err
 
-    from jax.experimental.shard_map import shard_map
     spec = jax.tree.map(lambda _: P(*axes), grads)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(spec, spec),
-                   out_specs=(spec, spec))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec, spec),
+                       out_specs=(spec, spec))
     return fn(grads, errors)
 
 

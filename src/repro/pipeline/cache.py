@@ -444,6 +444,10 @@ class KernelCache:
     def put_kernel(self, key: CacheKey, kernel) -> None:
         self._kernels[key] = kernel
 
+    def kernels(self) -> List[Any]:
+        """The compiled kernels held in process, in compile order."""
+        return list(self._kernels.values())
+
     # -- on-disk level ------------------------------------------------------
     def _paths(self, key: CacheKey) -> Tuple[Path, Path]:
         d = key.digest()

@@ -19,7 +19,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -71,7 +70,7 @@ def distributed_decode_attention(q, k_cache, v_cache, pos, mesh, *,
         g = h // hkv
         return out.reshape(b, h, 1, dh)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, None, seq_axis, None),
                   P(None, None, seq_axis, None), P()),

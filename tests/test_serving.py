@@ -137,6 +137,24 @@ def test_serve_config_run_api(fresh_cache):
     assert d["tokens_per_s"] > 0
 
 
+def test_grouped_max_rung_raises_instead_of_demoting(fresh_cache):
+    """``max_rung="grouped"`` (what the chip smoke test serves with): a
+    kernel the grouped rung cannot lower fails warm-up with the lowering
+    error in the message, and no rung below is tried."""
+    from repro import resilience as RZ
+
+    cfg = S.ServeConfig(arch="smollm-135m", backend="pallas", max_batch=2,
+                        max_len=48, prompt_buckets=(8,), max_rung="grouped")
+    engine = S.build_engine(cfg)
+    assert engine.cfg.pipeline_options.resilience.max_rung == "grouped"
+    plan = RZ.FaultPlan([RZ.FaultSpec(site="compile:grouped", indices=(0,),
+                                      kind="raise", message="refused")])
+    with RZ.faults(plan), pytest.raises(RZ.LadderError, match="refused") \
+            as ei:
+        engine.warmup()
+    assert [a.rung for a in ei.value.report.attempts] == ["grouped"]
+
+
 def test_engine_rejects_ssm_families():
     with pytest.raises(ValueError, match="attention-family"):
         Engine(configs.get_reduced_config("mamba2-2.7b"))
