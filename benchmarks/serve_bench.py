@@ -104,8 +104,10 @@ def _row(preset: str, cfg, report) -> dict:
     derived = ";".join([
         f"tokens_per_s={report.tokens_per_s:.1f}",
         f"decode_tokens_per_s={report.decode_tokens_per_s:.1f}",
-        f"p50_ms={report.p50_token_ms:.2f}",
-        f"p99_ms={report.p99_token_ms:.2f}",
+        f"ttft_p50_ms={report.ttft_p50_ms:.2f}",
+        f"ttft_p99_ms={report.ttft_p99_ms:.2f}",
+        f"itl_p50_ms={report.itl_p50_ms:.2f}",
+        f"itl_p99_ms={report.itl_p99_ms:.2f}",
         f"mean_occupancy={report.mean_occupancy:.2f}",
         f"max_queue_depth={report.max_queue_depth}",
         f"steps={report.steps}",

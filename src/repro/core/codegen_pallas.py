@@ -173,10 +173,11 @@ def _block_spec(vt, item_shape, dims, grid_axes) -> pl.BlockSpec:
 
 def _call_rows(kernel, merged_inputs, *, grid, in_specs, out_specs,
                out_full, scratch, donate, in_layouts, out_layouts,
-               interpret):
+               interpret, name=None):
     """``pallas_call`` over merged arrays, with rank-1 operands passed
     as ``(1, n)`` rows (:func:`_row`) and returned in their merged
-    shapes."""
+    shapes.  ``name`` names the kernel (the Mosaic custom call, and so
+    its op in a profiler trace); it changes nothing else."""
     dtype = (jnp.result_type(*merged_inputs) if merged_inputs
              else jnp.float32)
     outs = pl.pallas_call(
@@ -189,6 +190,7 @@ def _call_rows(kernel, merged_inputs, *, grid, in_specs, out_specs,
         input_output_aliases=_alias_map(merged_inputs, out_full, dtype,
                                         donate, in_layouts, out_layouts),
         interpret=interpret,
+        name=name,
     )(*[a.reshape(_row(a.shape)) for a in merged_inputs])
     return tuple(o.reshape(s) for o, s in zip(outs, out_full))
 
@@ -441,7 +443,8 @@ def _alias_map(merged_inputs, out_shapes, dtype, donate,
 
 def emit_region(spec: RegionSpec, dims: Dict[str, int],
                 in_item_shapes: List[Tuple[int, ...]], interpret: bool,
-                donate: Optional[Sequence[bool]] = None):
+                donate: Optional[Sequence[bool]] = None,
+                name: Optional[str] = None):
     """Lower one region to a single multi-output ``pallas_call``.
 
     Returns ``(fn, out_item_shapes, report)`` where ``fn`` maps merged
@@ -651,7 +654,8 @@ def emit_region(spec: RegionSpec, dims: Dict[str, int],
                           in_specs=in_specs, out_specs=out_specs,
                           out_full=out_full, scratch=scratch,
                           donate=donate, in_layouts=in_layouts,
-                          out_layouts=out_layouts, interpret=interpret)
+                          out_layouts=out_layouts, interpret=interpret,
+                          name=name)
 
     report = RegionReport(spec.label, tuple(grid_dims), red_dim, n_out)
     return region_fn, out_item_shapes, report
@@ -659,7 +663,8 @@ def emit_region(spec: RegionSpec, dims: Dict[str, int],
 
 def emit_group(group, types: Dict[Ref, VType], dims: Dict[str, int],
                in_item_shapes: List[Tuple[int, ...]], interpret: bool,
-               donate: Optional[Sequence[bool]] = None):
+               donate: Optional[Sequence[bool]] = None,
+               name: Optional[str] = None):
     """Lower one region *group* to a single multi-stage ``pallas_call``.
 
     The kernel grid is the group's shared parallel spine; every member
@@ -740,7 +745,7 @@ def emit_group(group, types: Dict[Ref, VType], dims: Dict[str, int],
                           in_specs=in_specs, out_specs=out_specs,
                           out_full=out_full, scratch=(), donate=donate,
                           in_layouts=in_layouts, out_layouts=out_layouts,
-                          interpret=interpret)
+                          interpret=interpret, name=name)
 
     reports = [RegionReport(spec.label, spec.grid_dims, spec.red_dim,
                             len(spec.out_refs), group=group.gid)
@@ -785,7 +790,8 @@ def _fallback_region(spec: RegionSpec, dims: Dict[str, int],
 def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
                  interpret="auto",
                  program_plan: Optional[ProgramPlan] = None,
-                 grouped_plan=None, group: bool = True
+                 grouped_plan=None, group: bool = True,
+                 name: Optional[str] = None
                  ) -> Tuple[Callable[..., Tuple], LoweringReport]:
     """Lower every region of (the partition of) ``g``.
 
@@ -801,7 +807,10 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
     by ``tests/test_lowering_coverage.py``).  Callers that already
     partitioned/grouped ``g`` (the driver shares one plan between
     lowering and per-kernel cost attribution) pass it via
-    ``program_plan``/``grouped_plan``."""
+    ``program_plan``/``grouped_plan``.  ``name`` names the emitted
+    kernels by kind, with the group index appended where the program
+    emits more than one (``rmsnorm_swiglu_0``, ``rmsnorm_swiglu_1``...),
+    so a profiler trace tells them apart; it changes no numerics."""
     interpret = resolve_interpret(interpret)
     try:
         pp = program_plan if program_plan is not None else plan(g)
@@ -862,12 +871,17 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
     # the planned grouping
     emitted: List[Tuple[str, Any]] = []
 
+    def kernel_name(gi: int) -> Optional[str]:
+        if name is None or len(gp.groups) == 1:
+            return name
+        return f"{name}_{gi}"
+
     def lower_one(spec: RegionSpec, gid: str, gi: int) -> None:
         in_items = [item_shapes[r] for r in spec.in_refs]
         try:
             fn, out_items, rep = emit_region(
                 spec, dims, in_items, interpret,
-                donate=donatable(spec.in_refs, gi))
+                donate=donatable(spec.in_refs, gi), name=kernel_name(gi))
         except (RegionError, NotImplementedError) as err:
             fn, out_items, rep = _fallback_region(spec, dims, in_items,
                                                   str(err))
@@ -887,7 +901,7 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
             in_items = [item_shapes[r] for r in grp.in_refs]
             fn, out_items, reps = emit_group(
                 grp, types, dims, in_items, interpret,
-                donate=donatable(grp.in_refs, gi))
+                donate=donatable(grp.in_refs, gi), name=kernel_name(gi))
         except (RegionError, NotImplementedError) as err:
             # a group the emitter cannot express degrades to per-region
             # kernels (still Pallas when possible), never to one big

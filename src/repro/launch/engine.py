@@ -18,10 +18,16 @@ arrival trace feeds a slot-based scheduler that
   slot exhausted) to free slots for the queue.
 
 Observability: every step records queue depth, batch occupancy and the
-prefill/decode split; the report aggregates tokens/sec, p50/p99
-per-token latency, kernel-cache hit rate and the steady-state recompile
-count, and serializes to JSON (``benchmarks/serve_bench.py`` gates the
-throughput/latency numbers in CI).
+prefill/decode split; every admitted request gets a host-clock timeline
+(arrival, start of its own prefill, first token, finish) from which the
+report takes time to first token and the gap between tokens; the report
+also aggregates tokens/sec, kernel-cache hit rate and the steady-state
+recompile count, and serializes to JSON (``benchmarks/serve_bench.py``
+gates the throughput numbers in CI).  Under ``jax.profiler`` the run
+loop's phases show as host spans (``engine.run``, ``engine.tick``,
+``engine.admit``, ``engine.decode``, ``engine.sync.*``, ...) on the
+trace's clock, next to the device's ops; every readback of a device
+value sits in one ``engine.sync.*`` span.
 """
 
 from __future__ import annotations
@@ -33,6 +39,7 @@ from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro import resilience as RZ
 
@@ -58,6 +65,9 @@ class Request:
     # tokens recorded), each with a structured failure record.  None =
     # no deadline (the default; synthetic traces set none)
     deadline_step: Optional[int] = None
+    # when the request arrived, on the host clock (``time.perf_counter``);
+    # None = when ``Engine.run`` takes it from the trace into the queue
+    arrival_s: Optional[float] = None
 
 
 def synth_trace(n_requests: int, *, seed: int = 0,
@@ -99,6 +109,40 @@ class StepRecord:
 
 
 @dataclass
+class RequestTimeline:
+    """Host-clock stamps (``time.perf_counter``) of one admitted
+    request.  The engine fills them in as the request goes: the object
+    in a report keeps being updated by later ``run()`` calls until the
+    request finishes."""
+    rid: int
+    arrival_s: float
+    prefill_s: float                       # start of its own prefill
+    first_token_s: Optional[float] = None  # first token on the host
+    finish_s: Optional[float] = None       # its last token on the host
+    n_tokens: int = 0
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        if self.first_token_s is None:
+            return None
+        return self.first_token_s - self.arrival_s
+
+    @property
+    def itl_s(self) -> Optional[float]:
+        """Mean gap between the request's tokens after its first."""
+        if self.finish_s is None or self.n_tokens < 2:
+            return None
+        return (self.finish_s - self.first_token_s) / (self.n_tokens - 1)
+
+
+def _percentiles_ms(values: List[float]) -> Tuple[float, float]:
+    if not values:
+        return 0.0, 0.0
+    p50, p99 = np.percentile(values, [50, 99])
+    return float(p50) * 1e3, float(p99) * 1e3
+
+
+@dataclass
 class ServeReport:
     """What a serving run did, aggregated for gating and dashboards."""
     n_requests: int = 0
@@ -111,8 +155,13 @@ class ServeReport:
     decode_tokens: int = 0
     tokens_per_s: float = 0.0
     decode_tokens_per_s: float = 0.0
-    p50_token_ms: float = 0.0
-    p99_token_ms: float = 0.0
+    # from the timelines of the requests admitted in this run() call:
+    # arrival to first token, and the mean gap between a finished
+    # request's tokens after its first
+    ttft_p50_ms: float = 0.0
+    ttft_p99_ms: float = 0.0
+    itl_p50_ms: float = 0.0
+    itl_p99_ms: float = 0.0
     mean_occupancy: float = 0.0
     max_queue_depth: int = 0
     cache_memory_hits: int = 0
@@ -146,6 +195,7 @@ class ServeReport:
     failures: List[dict] = field(default_factory=list)
     tokens: Dict[int, List[int]] = field(default_factory=dict)
     per_step: List[StepRecord] = field(default_factory=list)
+    timeline: List[RequestTimeline] = field(default_factory=list)
 
     def to_json(self) -> dict:
         d = asdict(self)
@@ -165,6 +215,7 @@ class _Slot:
     last_token: int
     generated: List[int]
     deadline: Optional[int] = None
+    timeline: Optional[RequestTimeline] = None
 
 
 def _demote_cfg(cfg):
@@ -304,6 +355,9 @@ class Engine:
         self._jax = jax
 
         m, L = self.model, self.max_len
+        # a profiler trace shows these programs under their module names,
+        # jit__lambda, jit_decode_step and jit_insert: the benchmark's
+        # trace reduction finds them by those names (bench/trace.py)
         self._prefill = jax.jit(lambda p, t: m.prefill(p, t, max_len=L))
         self._decode = jax.jit(m.decode_step)
 
@@ -319,6 +373,7 @@ class Engine:
         self.caches = m.init_cache(self.max_batch, self.max_len)
         self.slots: List[Optional[_Slot]] = [None] * self.max_batch
         self.queue: deque = deque()
+        self._arrival: Dict[int, float] = {}  # rid -> arrival, while queued
         self._warm_stats = None
         self._base_stats = None      # cache counters at warmup start
         self._base_metrics = None    # resilience.METRICS at warmup start
@@ -396,53 +451,77 @@ class Engine:
         ``"deadline"`` (its deadline passed while queued), or
         ``"poisoned"`` (the prompt prefilled to non-finite logits — the
         slot stays free, co-batched sequences never see it)."""
+        plen = len(req.prompt)
+        bucket = self._bucket(plen)
+        arrival = self._arrival.pop(req.rid, None)
+        with span("engine.admit", rid=req.rid, bucket=bucket or 0):
+            if req.deadline_step is not None and step > req.deadline_step:
+                report.failures.append({
+                    "rid": req.rid, "reason": "deadline_queued",
+                    "step": step, "deadline": req.deadline_step})
+                return "deadline"
+            if bucket is None or plen + req.max_new_tokens > self.max_len:
+                report.failures.append({
+                    "rid": req.rid, "reason": "bad_shape", "step": step,
+                    "prompt_len": plen,
+                    "max_new_tokens": req.max_new_tokens})
+                return "rejected"
+            t = time.perf_counter()
+            # a request put on the queue by hand arrives when admitted
+            tl = RequestTimeline(req.rid, t if arrival is None else arrival,
+                                 t)
+            report.timeline.append(tl)
+            return self._prefill_into(req, bucket, slot, tl, report, step)
+
+    def _prefill_into(self, req: Request, bucket: int, slot: int,
+                      tl: RequestTimeline, report: ServeReport,
+                      step: int) -> str:
         jnp = self._jax.numpy
         plen = len(req.prompt)
-        if req.deadline_step is not None and step > req.deadline_step:
-            report.failures.append({
-                "rid": req.rid, "reason": "deadline_queued", "step": step,
-                "deadline": req.deadline_step})
-            return "deadline"
-        bucket = self._bucket(plen)
-        if bucket is None or plen + req.max_new_tokens > self.max_len:
-            report.failures.append({
-                "rid": req.rid, "reason": "bad_shape", "step": step,
-                "prompt_len": plen, "max_new_tokens": req.max_new_tokens})
-            return "rejected"
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :plen] = req.prompt
-        logits, cache = self._prefill(self.params, jnp.asarray(padded))
-        # the prompt's next-token logits sit at the last REAL position;
-        # pad positions to the right are causally invisible to it
-        row = logits[:, plen - 1:plen]
-        if not bool(jnp.all(jnp.isfinite(row))):
+        with span("engine.prefill"):
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :plen] = req.prompt
+            logits, cache = self._prefill(self.params, jnp.asarray(padded))
+            # the prompt's next-token logits sit at the last REAL
+            # position; pad positions to the right are causally
+            # invisible to it
+            row = logits[:, plen - 1:plen]
+        with span("engine.sync.prefill_guard"):
+            finite = bool(jnp.all(jnp.isfinite(row)))
+        if not finite:
             # poison prompt: never insert its cache, never occupy a slot
+            tl.finish_s = time.perf_counter()
             report.n_poisoned += 1
             report.failures.append({
                 "rid": req.rid, "reason": "nonfinite_prefill",
                 "step": step, "prompt_len": plen})
             return "poisoned"
-        self.caches = self._insert(self.caches, cache, slot)
-        first = self._sample(row)
-        tok = int(first[0])
+        with span("engine.insert"):
+            self.caches = self._insert(self.caches, cache, slot)
+        with span("engine.sync.first_token"):
+            tok = int(self._sample(row)[0])
+        tl.first_token_s = time.perf_counter()
+        tl.n_tokens = 1
         if req.max_new_tokens <= 1:
             # the prefill's token satisfies the request outright
+            tl.finish_s = tl.first_token_s
             report.tokens[req.rid] = [tok]
             report.n_completed += 1
             return "ok"
         self.slots[slot] = _Slot(rid=req.rid, pos=plen,
                                  remaining=req.max_new_tokens - 1,
                                  last_token=tok, generated=[tok],
-                                 deadline=req.deadline_step)
+                                 deadline=req.deadline_step, timeline=tl)
         return "ok"
 
     def _decode_once(self):
         jnp = self._jax.numpy
-        RZ.check("serve:decode")
-        return self._decode(
-            self.params, self.caches,
-            jnp.asarray(self._token_vector()[:, None]),
-            jnp.asarray(self._pos_vector()))
+        with span("engine.decode"):
+            RZ.check("serve:decode")
+            return self._decode(
+                self.params, self.caches,
+                jnp.asarray(self._token_vector()[:, None]),
+                jnp.asarray(self._pos_vector()))
 
     def _watchdog_demote(self, err: BaseException, step: int,
                          report: ServeReport) -> None:
@@ -505,7 +584,9 @@ class Engine:
                 jnp.asarray(self._pos_vector()))
             if spec is not None and spec.kind == "nan":
                 logits = logits.at[:, -1].set(jnp.nan)
-            if not bool(jnp.all(jnp.isfinite(logits[:, -1]))):
+            with span("engine.sync.probe_guard"):
+                finite = bool(jnp.all(jnp.isfinite(logits[:, -1])))
+            if not finite:
                 raise RuntimeError("probe produced non-finite logits")
         except Exception as e:
             self.probe_failures += 1
@@ -541,16 +622,19 @@ class Engine:
     def run(self, trace: Sequence[Request],
             max_steps: Optional[int] = None) -> ServeReport:
         """Drive the trace to completion (or ``max_steps``) and report."""
+        with span("engine.run"):
+            return self._run(trace, max_steps)
+
+    def _run(self, trace: Sequence[Request],
+             max_steps: Optional[int]) -> ServeReport:
         from repro import pipeline
 
-        jnp = self._jax.numpy
         if self._warm_stats is None:
             self.warmup()
         stats = pipeline.default_cache().stats
 
         pending = deque(sorted(trace, key=lambda r: r.arrival_step))
         report = ServeReport(n_requests=len(trace))
-        token_lat_ms: List[float] = []
         occupancy_sum = 0
         step = 0
         t_run = time.perf_counter()
@@ -559,105 +643,11 @@ class Engine:
                 break
             self._tick = step  # the health ledger's deterministic clock
             t0 = time.perf_counter()
-            while pending and pending[0].arrival_step <= step:
-                req = pending.popleft()
-                if (self.max_queue is not None
-                        and len(self.queue) >= self.max_queue):
-                    # bounded admission: reject loudly instead of
-                    # building an unbounded backlog
-                    report.n_rejected += 1
-                    report.failures.append({
-                        "rid": req.rid, "reason": "queue_full",
-                        "step": step, "queue_depth": len(self.queue)})
-                else:
-                    self.queue.append(req)
-            n_prefill = 0
-            for slot in self._free_slots():
-                if not self.queue:
-                    break
-                req = self.queue.popleft()
-                status = self._admit(req, slot, report, step)
-                if status == "ok":
-                    n_prefill += 1
-                    report.prefill_tokens += len(req.prompt)
-                    report.decode_tokens += 1  # the prefill's first token
-                elif status == "rejected":
-                    report.n_rejected += 1
-                elif status == "deadline":
-                    report.n_deadline_evicted += 1
-                # "poisoned" is counted inside _admit; the slot stays
-                # free either way and co-batched sequences are untouched
-            active = [i for i, s in enumerate(self.slots) if s is not None]
-            n_decode = 0
-            if active:
-                try:
-                    logits, caches = self._decode_once()
-                    self._clean_ticks += 1
-                except Exception as e:  # watchdog: demote, retry once
-                    before = stats.snapshot()
-                    self._watchdog_demote(e, step, report)
-                    logits, caches = self._decode_once()
-                    # the demoted decode's compiles are explained — keep
-                    # strict_no_recompile armed for *unexplained* ones
-                    self.demotion_compiles += stats.delta(before).compiles
-                    self._warm_stats = stats.snapshot()
-                    self._clean_ticks = 0
-                self.caches = caches
-                spec = RZ.fire("serve:logits")
-                if spec is not None and spec.kind == "nan":
-                    # poison exactly one co-batched row; the guard below
-                    # must contain it to that sequence
-                    logits = logits.at[active[0], -1].set(jnp.nan)
-                # cheap post-step guard: one finite-check over the new
-                # logits row per slot, evict poisoned sequences instead
-                # of letting NaNs propagate through their KV cache
-                fin = np.asarray(jnp.all(jnp.isfinite(logits[:, -1]),
-                                         axis=-1))
-                for i in active:
-                    if bool(fin[i]):
-                        continue
-                    s = self.slots[i]
-                    report.n_poisoned += 1
-                    report.failures.append({
-                        "rid": s.rid, "reason": "nonfinite_logits",
-                        "step": step, "pos": s.pos})
-                    report.tokens[s.rid] = s.generated  # partial output
-                    self.slots[i] = None
-                active = [i for i in active if bool(fin[i])]
-                sampled = self._sample(logits)
-                for i in active:
-                    s = self.slots[i]
-                    tok = int(sampled[i])
-                    s.pos += 1
-                    s.generated.append(tok)
-                    s.last_token = tok
-                    s.remaining -= 1
-                    n_decode += 1
-                    if s.remaining <= 0 or s.pos >= self.max_len:
-                        # finished (request satisfied) or stalled (slot
-                        # exhausted): free the slot for the queue
-                        if s.remaining > 0:
-                            report.n_evicted_stalled += 1
-                        else:
-                            report.n_completed += 1
-                        report.tokens[s.rid] = s.generated
-                        self.slots[i] = None
-                    elif s.deadline is not None and step >= s.deadline:
-                        report.n_deadline_evicted += 1
-                        report.failures.append({
-                            "rid": s.rid, "reason": "deadline",
-                            "step": step, "deadline": s.deadline})
-                        report.tokens[s.rid] = s.generated
-                        self.slots[i] = None
-            # re-promotion: after enough clean ticks on a demoted rung,
-            # let the breaker admit one half-open probe of the original
-            if (self._demote_stack and self._ledger is not None
-                    and self._clean_ticks >= self.repromote_after
-                    and self._ledger.decision(
-                        self._hkey, self._demote_stack[-1][1]) == "probe"):
-                self._probe_repromote(report, step, stats)
+            with span("engine.tick", step=step, n_active=sum(
+                    s is not None for s in self.slots)):
+                n_prefill, n_decode = self._step(step, pending, report,
+                                                 stats)
             wall_ms = (time.perf_counter() - t0) * 1e3
-            token_lat_ms.extend([wall_ms] * (n_decode + n_prefill))
             occ = sum(1 for s in self.slots if s is not None)
             occupancy_sum += occ
             report.decode_tokens += n_decode
@@ -676,9 +666,10 @@ class Engine:
         report.tokens_per_s = total / max(report.wall_s, 1e-9)
         report.decode_tokens_per_s = (report.decode_tokens
                                       / max(report.wall_s, 1e-9))
-        if token_lat_ms:
-            report.p50_token_ms = float(np.percentile(token_lat_ms, 50))
-            report.p99_token_ms = float(np.percentile(token_lat_ms, 99))
+        report.ttft_p50_ms, report.ttft_p99_ms = _percentiles_ms(
+            [t.ttft_s for t in report.timeline if t.ttft_s is not None])
+        report.itl_p50_ms, report.itl_p99_ms = _percentiles_ms(
+            [t.itl_s for t in report.timeline if t.itl_s is not None])
         report.mean_occupancy = occupancy_sum / max(step, 1)
         report.cache_memory_hits = stats.memory_hits
         report.cache_disk_hits = stats.disk_hits
@@ -703,3 +694,133 @@ class Engine:
                 "warmup — a steady-state decode step compiled a kernel "
                 "(shape bucket or batch drifted out of the warmed set)")
         return report
+
+    def _step(self, step: int, pending: deque, report: ServeReport,
+              stats) -> Tuple[int, int]:
+        """One tick: take arrivals, admit into free slots, advance every
+        active slot one token.  Returns ``(n_prefill, n_decode)``."""
+        jnp = self._jax.numpy
+        now = time.perf_counter()
+        while pending and pending[0].arrival_step <= step:
+            req = pending.popleft()
+            if (self.max_queue is not None
+                    and len(self.queue) >= self.max_queue):
+                # bounded admission: reject loudly instead of building
+                # an unbounded backlog
+                report.n_rejected += 1
+                report.failures.append({
+                    "rid": req.rid, "reason": "queue_full",
+                    "step": step, "queue_depth": len(self.queue)})
+            else:
+                self._arrival[req.rid] = (now if req.arrival_s is None
+                                          else req.arrival_s)
+                self.queue.append(req)
+        n_prefill = 0
+        for slot in self._free_slots():
+            if not self.queue:
+                break
+            req = self.queue.popleft()
+            status = self._admit(req, slot, report, step)
+            if status == "ok":
+                n_prefill += 1
+                report.prefill_tokens += len(req.prompt)
+                report.decode_tokens += 1  # the prefill's first token
+            elif status == "rejected":
+                report.n_rejected += 1
+            elif status == "deadline":
+                report.n_deadline_evicted += 1
+            # "poisoned" is counted inside _admit; the slot stays free
+            # either way and co-batched sequences are untouched
+        active = [i for i, s in enumerate(self.slots) if s is not None]
+        n_decode = 0
+        if active:
+            try:
+                logits, caches = self._decode_once()
+                self._clean_ticks += 1
+            except Exception as e:  # watchdog: demote, retry once
+                before = stats.snapshot()
+                self._watchdog_demote(e, step, report)
+                logits, caches = self._decode_once()
+                # the demoted decode's compiles are explained — keep
+                # strict_no_recompile armed for *unexplained* ones
+                self.demotion_compiles += stats.delta(before).compiles
+                self._warm_stats = stats.snapshot()
+                self._clean_ticks = 0
+            self.caches = caches
+            spec = RZ.fire("serve:logits")
+            if spec is not None and spec.kind == "nan":
+                # poison exactly one co-batched row; the guard below
+                # must contain it to that sequence
+                logits = logits.at[active[0], -1].set(jnp.nan)
+            # cheap post-step guard: one finite-check over the new
+            # logits row per slot, evict poisoned sequences instead of
+            # letting NaNs propagate through their KV cache
+            with span("engine.sync.decode_guard"):
+                fin = np.asarray(jnp.all(jnp.isfinite(logits[:, -1]),
+                                         axis=-1))
+            with span("engine.sync.sample"):
+                sampled = self._sample(logits)
+            now = time.perf_counter()
+            with span("engine.retire"):
+                n_decode = self._retire(active, fin, sampled, now, step,
+                                        report)
+        # re-promotion: after enough clean ticks on a demoted rung, let
+        # the breaker admit one half-open probe of the original
+        if (self._demote_stack and self._ledger is not None
+                and self._clean_ticks >= self.repromote_after
+                and self._ledger.decision(
+                    self._hkey, self._demote_stack[-1][1]) == "probe"):
+            with span("engine.repromote"):
+                self._probe_repromote(report, step, stats)
+        return n_prefill, n_decode
+
+    def _retire(self, active: List[int], fin: np.ndarray,
+                sampled: np.ndarray, now: float, step: int,
+                report: ServeReport) -> int:
+        """Slot bookkeeping after a decode step: evict poisoned rows,
+        append each sampled token (at host time ``now``), free finished,
+        stalled and expired slots.  Returns the tokens emitted."""
+
+        def free(i: int, s: _Slot) -> None:
+            report.tokens[s.rid] = s.generated  # whole or partial output
+            if s.timeline is not None:
+                s.timeline.finish_s = now
+            self.slots[i] = None
+
+        for i in active:
+            if bool(fin[i]):
+                continue
+            s = self.slots[i]
+            report.n_poisoned += 1
+            report.failures.append({
+                "rid": s.rid, "reason": "nonfinite_logits",
+                "step": step, "pos": s.pos})
+            free(i, s)
+        n_decode = 0
+        for i in active:
+            if not bool(fin[i]):
+                continue
+            s = self.slots[i]
+            tok = int(sampled[i])
+            s.pos += 1
+            s.generated.append(tok)
+            s.last_token = tok
+            s.remaining -= 1
+            n_decode += 1
+            if s.timeline is not None:
+                s.timeline.n_tokens += 1
+            if s.remaining <= 0 or s.pos >= self.max_len:
+                # finished (request satisfied) or stalled (slot
+                # exhausted): free the slot for the queue
+                if s.remaining > 0:
+                    report.n_evicted_stalled += 1
+                else:
+                    report.n_completed += 1
+                free(i, s)
+            elif s.deadline is not None and step >= s.deadline:
+                report.n_deadline_evicted += 1
+                report.failures.append({
+                    "rid": s.rid, "reason": "deadline",
+                    "step": step, "deadline": s.deadline})
+                free(i, s)
+        return n_decode

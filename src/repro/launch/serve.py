@@ -156,7 +156,8 @@ def main(argv=None) -> ServeReport:
           f"requests={report.n_completed}/{report.n_requests} "
           f"steps={report.steps} tokens={report.decode_tokens} "
           f"({report.tokens_per_s:.1f} tok/s incl. prefill) "
-          f"p50={report.p50_token_ms:.2f}ms p99={report.p99_token_ms:.2f}ms "
+          f"ttft p50/p99={report.ttft_p50_ms:.2f}/{report.ttft_p99_ms:.2f}ms "
+          f"itl p50/p99={report.itl_p50_ms:.2f}/{report.itl_p99_ms:.2f}ms "
           f"occupancy={report.mean_occupancy:.2f} "
           f"cache_hit_rate={report.cache_hit_rate:.3f} "
           f"recompiles={report.decode_recompiles} "

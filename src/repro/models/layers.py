@@ -87,7 +87,8 @@ def _attention_kernel(s: int, dh: int, sk: int, dv: int, group: int,
         g = AP.causal_attention_program(scale)
     else:
         g = AP.attention_program(scale)
-    return PL.compile(g, dims, options=options.replace(blocks=blocks))
+    return PL.compile(g, dims, options=options.replace(blocks=blocks),
+                      name="attention")
 
 
 @functools.lru_cache(maxsize=256)
@@ -98,7 +99,7 @@ def _swiglu_kernel(t: int, d: int, d_ff: int, eps: float, options):
         {"M": t, "D": d, "K": d_ff, "N": d})
     return PL.compile(
         AP.rmsnorm_ffn_swiglu_program(float(d), eps=eps), dims,
-        options=options.replace(blocks=blocks))
+        options=options.replace(blocks=blocks), name="rmsnorm_swiglu")
 
 
 def _attention_pipeline(q, k, v, scale: float, options, *,

@@ -223,7 +223,8 @@ def _grouped_plan(pplan, dims: Dict[str, int],
 def _lower_pallas(g: Graph, dims: Dict[str, int],
                   blocks: Optional[Dict[str, int]], interpret: bool,
                   program_plan=None, grouped_plan=None,
-                  group: bool = True, jit: bool = True):
+                  group: bool = True, jit: bool = True,
+                  name: Optional[str] = None):
     """Lower the selected snapshot itself — no walking back to a
     differently-fused candidate.  Returns (call, LoweringReport).  The
     chained kernel schedule runs under ``jax.jit`` (when ``jit``) so
@@ -239,7 +240,8 @@ def _lower_pallas(g: Graph, dims: Dict[str, int],
         raise ValueError(f"blocks missing sizes for dims {missing}")
     f, report = emit_program(g, dims, blocks, interpret=interpret,
                              program_plan=program_plan,
-                             grouped_plan=grouped_plan, group=group)
+                             grouped_plan=grouped_plan, group=group,
+                             name=name)
     if report.fallbacks:
         warnings.warn(
             "pallas lowering fallback: "
@@ -261,7 +263,8 @@ def _lower_pallas(g: Graph, dims: Dict[str, int],
 
 def _rung_thunk(rung: str, g: Graph, dims: Dict[str, int], *,
                 blocks: Optional[Dict[str, int]], interpret, jit,
-                pplan, gplan, group: bool) -> Callable[[], Tuple]:
+                pplan, gplan, group: bool,
+                name: Optional[str] = None) -> Callable[[], Tuple]:
     """The lowering a ladder rung runs; every thunk returns
     ``(call, LoweringReport-or-None)``.  ``gplan`` is only reusable at
     the rung it was packed for — a demoted rung recomputes its own."""
@@ -269,12 +272,12 @@ def _rung_thunk(rung: str, g: Graph, dims: Dict[str, int], *,
         return lambda: _lower_pallas(
             g, dims, blocks, interpret, program_plan=pplan,
             grouped_plan=gplan if group else None, group=True,
-            jit=bool(jit))
+            jit=bool(jit), name=name)
     if rung == "ungrouped":
         return lambda: _lower_pallas(
             g, dims, blocks, interpret, program_plan=pplan,
             grouped_plan=None if group else gplan, group=False,
-            jit=bool(jit))
+            jit=bool(jit), name=name)
     if rung == "jax":
         return lambda: (_lower_jax(g, dims, jit), None)
     return lambda: (_lower_py(g, dims), None)
@@ -438,6 +441,7 @@ def compile(graph: Graph, dims: Optional[Dict[str, int]] = None, *,
             options: Optional[CompileOptions] = None,
             dim_candidates: Optional[Dict[str, Sequence[int]]] = None,
             cache: Optional[KernelCache] = None,
+            name: Optional[str] = None,
             **kwargs) -> CompiledKernel:
     """Compile a block program into an executing, cached kernel.
 
@@ -494,6 +498,11 @@ def compile(graph: Graph, dims: Optional[Dict[str, int]] = None, *,
     backends keep the default constants until calibrated (see
     ``core/calibrate.py``).  The analytic path always keeps the
     deterministic defaults.
+
+    ``name`` (pallas backend) names the emitted kernels by kind
+    (``codegen_pallas.emit_program``), so that a profiler trace shows
+    which kernel ran.  It is no part of the cache key: a program is
+    served from the cache under the name it was first compiled with.
     """
     if options is None:
         try:
@@ -675,7 +684,7 @@ def compile(graph: Graph, dims: Optional[Dict[str, int]] = None, *,
             functools.partial(_rung_thunk, g=selected_graph,
                               dims=use_dims, blocks=blocks,
                               interpret=interpret, jit=jit, pplan=pplan,
-                              gplan=gplan, group=group),
+                              gplan=gplan, group=group, name=name),
             policy, rr,
             # the cache's health ledger shares breaker state with every
             # process pointed at the same cache dir; keyed by graph

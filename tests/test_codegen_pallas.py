@@ -59,3 +59,26 @@ def test_rmsnorm_swiglu_kernel_autogen(rng):
     gsw = xn @ W
     ref = ((gsw / (1 + np.exp(-gsw))) * (xn @ V)) @ U
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4, rtol=1e-4)
+
+
+def test_kernel_name_changes_neither_key_nor_numerics(rng, tmp_path):
+    """A kernel's name labels it in a profiler trace and nothing more:
+    the named and the unnamed compile share one cache key and give the
+    same numbers."""
+    from repro import pipeline
+
+    dims = {"M": 2, "D": 2, "N": 4, "L": 2}
+    opts = pipeline.CompileOptions(
+        backend="pallas", interpret=True,
+        blocks={"M": 8, "D": 16, "N": 8, "L": 16})
+    g = AP.attention_program(scale=0.125)
+    feed = {"Q": rng.normal(size=(16, 32)).astype(np.float32),
+            "KT": rng.normal(size=(32, 32)).astype(np.float32),
+            "VT": rng.normal(size=(32, 32)).astype(np.float32)}
+    named, unnamed = (
+        pipeline.compile(g, dims, options=opts, name=name,
+                         cache=pipeline.KernelCache(tmp_path / str(i)))
+        for i, name in enumerate(["attention", None]))
+    assert named.key == unnamed.key
+    np.testing.assert_array_equal(np.asarray(named(feed)["O"]),
+                                  np.asarray(unnamed(feed)["O"]))

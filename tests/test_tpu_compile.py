@@ -14,6 +14,7 @@ call must not happen while modules are imported (see the fixture).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,11 +79,17 @@ def _compile_for_chip(kern, shapes, one_chip) -> str:
     return jax.jit(step).lower(*args).compile().as_text()
 
 
-def _assert_chip_kernel(kern, hlo):
+def _assert_chip_kernel(kern, hlo, name):
     assert kern.rung == "grouped"
     assert dict(kern.key.opts)["interpret"] is False
     assert kern.lowering_report.fallbacks == 0
-    assert "tpu_custom_call" in hlo
+    # every Mosaic call carries the kernel's name, which is what the
+    # profiler's "XLA Ops" line shows for it
+    calls = [line.split(" = ")[0].strip()
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == kern.launches >= 1
+    for call in calls:
+        assert re.fullmatch(rf"(ROOT )?%{name}(_\d+)?\.\d+", call), call
 
 
 @pytest.mark.parametrize("tokens", [8, 256])
@@ -93,7 +100,7 @@ def test_swiglu_compiles_for_v5e(tokens, one_chip, chip_options):
     hlo = _compile_for_chip(kern, {
         "X": (tokens, D_MODEL), "WT": (D_FF, D_MODEL),
         "VT": (D_FF, D_MODEL), "UT": (D_MODEL, D_FF)}, one_chip)
-    _assert_chip_kernel(kern, hlo)
+    _assert_chip_kernel(kern, hlo, "rmsnorm_swiglu")
 
 
 @pytest.mark.parametrize("sq", [1, 256])
@@ -108,4 +115,18 @@ def test_causal_gqa_attention_compiles_for_v5e(sq, one_chip, chip_options):
     hlo = _compile_for_chip(kern, {
         "Q": (GROUP, sq, D_HEAD), "KT": (sk, D_HEAD), "VT": (D_HEAD, sk),
         "QP": (sq,), "KP": (sk,)}, one_chip)
-    _assert_chip_kernel(kern, hlo)
+    _assert_chip_kernel(kern, hlo, "attention")
+
+
+def test_wide_swiglu_names_each_group_for_v5e(one_chip, chip_options):
+    """At qwen2-7b's widths (d_model 3584, d_ff 18944) the SwiGLU program
+    emits three kernels under the VMEM budget; each carries the kind's
+    name and its group index."""
+    d, f, t = 3584, 18944, 32
+    kern = LY._swiglu_kernel(t, d, f, 1e-6, chip_options)
+    hlo = _compile_for_chip(kern, {
+        "X": (t, d), "WT": (f, d), "VT": (f, d), "UT": (d, f)}, one_chip)
+    _assert_chip_kernel(kern, hlo, "rmsnorm_swiglu")
+    assert {f"rmsnorm_swiglu_{i}" for i in range(kern.launches)} == {
+        re.sub(r"\.\d+ = .*", "", line.strip().removeprefix("ROOT ")[1:])
+        for line in hlo.splitlines() if "tpu_custom_call" in line}
