@@ -32,6 +32,7 @@ whole-resident in VMEM and in-kernel loops slice them.
 
 from __future__ import annotations
 
+import math
 import warnings
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -82,11 +83,14 @@ class LoweringReport:
     every fallback taken (which must be zero for in-repo programs), how
     many kernels actually launch (grouped regions share one), and how
     many cross-region values stayed VMEM-resident instead of
-    round-tripping through global memory."""
+    round-tripping through global memory, and how many grid steps one
+    call runs (the product of each Pallas kernel's grid extents, summed
+    over its kernels; a fallback region runs no grid)."""
 
     regions: List[RegionReport] = field(default_factory=list)
     launches: int = 0
     resident_edges: int = 0
+    grid_steps: int = 0
     # the RegionError that made partitioning fall back to one
     # whole-program jax region (None when the partitioner succeeded) —
     # recorded so check_regression.py and the serve warmup fallback
@@ -871,6 +875,9 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
     # the planned grouping
     emitted: List[Tuple[str, Any]] = []
 
+    def grid_steps(axes: Sequence[str]) -> int:
+        return math.prod(dims[d] for d in axes)
+
     def kernel_name(gi: int) -> Optional[str]:
         if name is None or len(gp.groups) == 1:
             return name
@@ -886,6 +893,9 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
             fn, out_items, rep = _fallback_region(spec, dims, in_items,
                                                   str(err))
         rep = replace(rep, group=gid)
+        if rep.fallback is None:
+            report.grid_steps += grid_steps(
+                spec.grid_dims + ((spec.red_dim,) if spec.red_dim else ()))
         for ref, ish in zip(spec.out_refs, out_items):
             item_shapes[ref] = ish
         lowered.append((KernelRun(gid, rep.label, tuple(spec.in_refs),
@@ -919,6 +929,7 @@ def emit_program(g: Graph, dims: Dict[str, int], blocks: Dict[str, int],
         emitted.append((grp.gid, grp))
         report.regions.extend(reps)
         report.resident_edges += len(grp.resident)
+        report.grid_steps += grid_steps(grp.grid_dims)
     report.launches = len(lowered)
 
     out_refs: List[Ref] = []

@@ -68,17 +68,21 @@ def _pipeline_options(src):
 
 @functools.lru_cache(maxsize=256)
 def _attention_kernel(s: int, dh: int, sk: int, dv: int, group: int,
-                      causal: bool, scale: float, options):
-    """One compiled kernel per (shape, group, causal, scale, options); the
-    lru_cache skips graph reconstruction + fingerprinting on every forward
-    call (CompileOptions is hashable, so it keys directly).  Query
-    positions are kernel *data* (QP/KP inputs), so a decode step at any
-    cache position — scalar or a ragged per-sequence position vector —
-    reuses the same compiled kernel."""
+                      causal: bool, scale: float, options,
+                      kv_tile: Optional[int] = None):
+    """One compiled kernel per (shape, group, causal, scale, options,
+    kv_tile); the lru_cache skips graph reconstruction + fingerprinting
+    on every forward call (CompileOptions is hashable, so it keys
+    directly).  Query positions are kernel *data* (QP/KP inputs), so a
+    decode step at any cache position — scalar or a ragged per-sequence
+    position vector — reuses the same compiled kernel.  ``kv_tile`` sets
+    the keys per N block (default: :func:`_n_blocks`)."""
     from repro import pipeline as PL
     from repro.core import array_program as AP
     dims, blocks = _pipeline_dims_blocks(
         {"M": s, "D": dh, "N": sk, "L": dv})
+    if kv_tile is not None:
+        dims["N"], blocks["N"] = sk // kv_tile, kv_tile
     if group > 1:
         g = AP.gqa_attention_program(scale, causal=causal)
         dims["H"] = group
@@ -89,6 +93,67 @@ def _attention_kernel(s: int, dh: int, sk: int, dv: int, group: int,
         g = AP.attention_program(scale)
     return PL.compile(g, dims, options=options.replace(blocks=blocks),
                       name="attention")
+
+
+# Query rows per KV head up to which an attention call is decode-shaped:
+# a one-token step at any GQA group up to 32, or a few tokens each.
+# Prefill buckets (64 tokens and up) stay above it.
+DECODE_ROWS = 32
+
+
+def _kv_tile(sk: int, dh: int, dv: int) -> int:
+    """Keys per N tile of a decode-shaped call.  The whole cache when
+    one grid step's float32 K (sk, dh) and V^T (dv, sk) tiles, double-
+    buffered, fit the grouping VMEM budget (``regions.vmem_budget()``);
+    else the largest 128-multiple divisor of ``sk`` that fits, 128 at
+    least.  A cache that 128 does not divide stays whole, as
+    :func:`_n_blocks` keeps it."""
+    from repro.core import regions as REG
+    budget = REG.vmem_budget()
+
+    def fits(t: int) -> bool:
+        return 2 * 4 * t * (dh + dv) <= budget
+
+    if fits(sk) or sk % LANES:
+        return sk
+    return next((t for t in range(sk - LANES, 0, -LANES)
+                 if sk % t == 0 and fits(t)), LANES)
+
+
+def _attention_call(q_shape, k_shape, dv: int, causal: bool,
+                    scale: float, options):
+    """The kernel one attention call runs: ``(kernel, rows, group)``,
+    where ``rows`` are the query rows of one (sequence, KV head) and
+    ``group`` the head-group stack the kernel keeps.
+
+    A decode-shaped call (``group * sq <= DECODE_ROWS``) folds the GQA
+    group into the query rows (every head of a group reads the same
+    cache, at the same positions) and takes the cache as one N tile
+    where it fits (:func:`_kv_tile`): one grid step per (sequence, KV
+    head), each K/V block read once.  Larger calls (prefill) keep the
+    head-group program with one head per step and 128-key blocks."""
+    b, hq, sq, dh = q_shape
+    _, hkv, sk, _ = k_shape
+    group = hq // hkv
+    opts = _pipeline_options(options)
+    if group * sq <= DECODE_ROWS:
+        rows, group, tile = group * sq, 1, _kv_tile(sk, dh, dv)
+    else:
+        rows, tile = sq, None
+    kern = _attention_kernel(rows, dh, sk, dv, group, causal, scale, opts,
+                             tile)
+    return kern, rows, group
+
+
+def attention_grid_steps(q_shape, k_shape, dv: int, options, *,
+                         causal: bool = True) -> int:
+    """Grid steps of one attention call (q ``(b, hq, sq, dh)`` against a
+    ``(b, hkv, sk, dh)`` cache, at the layers' ``1/sqrt(dh)`` scale): the
+    kernel's own grid times the batch and KV-head axes the call maps it
+    over.  Static per shape."""
+    kern, _, _ = _attention_call(q_shape, k_shape, dv, causal,
+                                 1.0 / q_shape[3] ** 0.5, options)
+    return q_shape[0] * k_shape[1] * kern.lowering_report.grid_steps
 
 
 @functools.lru_cache(maxsize=256)
@@ -106,22 +171,23 @@ def _attention_pipeline(q, k, v, scale: float, options, *,
                         causal: bool = False, q_offset=0) -> jax.Array:
     """Attention through the fused pipeline — causal or not, MHA or GQA.
 
-    One compiled kernel per (shape, group, causal, options), vmapped over
-    batch and kv heads.  GQA runs the head-group block program (Q blocked
-    (H, M, D); K/V broadcast across the group).  Causal masking takes the
-    global query/key positions as kernel inputs, so decode (``q`` is one
-    token at cache position ``q_offset``) is the same program with M = 1
-    and needs no recompile as the position advances.  ``q_offset`` may be
-    a scalar (every sequence at the same position) or a ``(b,)`` vector
-    (ragged continuous-batching decode: each sequence at its own cache
-    position) — the ragged case maps the per-sequence position vector
-    into the kernel's QP input, same compiled kernel either way."""
-    opts = _pipeline_options(options)
+    One compiled kernel per shape (:func:`_attention_call`), vmapped over
+    batch and kv heads.  A decode-shaped call runs the group's heads as
+    the query rows of one plain attention program; a prefill runs the
+    head-group block program (Q blocked (H, M, D); K/V broadcast across
+    the group).  Causal masking takes the global query/key positions as
+    kernel inputs, so decode (``q`` is one token at cache position
+    ``q_offset``) needs no recompile as the position advances.
+    ``q_offset`` may be a scalar (every sequence at the same position)
+    or a ``(b,)`` vector (ragged continuous-batching decode: each
+    sequence at its own cache position) — the ragged case maps the
+    per-sequence position vector into the kernel's QP input, same
+    compiled kernel either way."""
     b, hq, sq, dh = q.shape
     _, hkv, skv, _ = k.shape
     dv = v.shape[3]
-    group = hq // hkv
-    kern = _attention_kernel(sq, dh, skv, dv, group, causal, scale, opts)
+    kern, rows, group = _attention_call(q.shape, k.shape, dv, causal,
+                                        scale, options)
     kp = jnp.arange(skv, dtype=jnp.float32)
 
     def one(qh, kh, vh, qp):
@@ -133,17 +199,18 @@ def _attention_pipeline(q, k, v, scale: float, options, *,
         return kern(feed)["O"]
 
     off = jnp.asarray(q_offset, dtype=jnp.float32)
-    qp = off[..., None] + jnp.arange(sq, dtype=jnp.float32)
+    # row r of a folded group is token r % sq of head r // sq
+    qp = off[..., None] + jnp.tile(jnp.arange(sq, dtype=jnp.float32),
+                                   rows // sq)
     # heads share the position vector; the batch axis maps it only when
     # q_offset is ragged (per-sequence)
     inner = jax.vmap(one, in_axes=(0, 0, 0, None))
     outer = jax.vmap(inner, in_axes=(0, 0, 0, 0 if off.ndim == 1 else None))
     if group > 1:
-        qg = q.reshape(b, hkv, group, sq, dh)
-        o = outer(qg, k, v, qp)                    # (b, hkv, group, sq, dv)
-        o = o.reshape(b, hq, sq, dv)
+        qg = q.reshape(b, hkv, group, sq, dh)      # o: (b, hkv, group, sq, dv)
     else:
-        o = outer(q, k, v, qp)
+        qg = q.reshape(b, hkv, rows, dh)           # o: (b, hkv, rows, dv)
+    o = outer(qg, k, v, qp).reshape(b, hq, sq, dv)
     return o.astype(q.dtype)
 
 

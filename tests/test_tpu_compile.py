@@ -105,9 +105,10 @@ def test_swiglu_compiles_for_v5e(tokens, one_chip, chip_options):
 
 @pytest.mark.parametrize("sq", [1, 256])
 def test_causal_gqa_attention_compiles_for_v5e(sq, one_chip, chip_options):
-    """Causal GQA attention, decode (sq=1) and prefill (sq=256), over a
-    1024-long cache: eight 128-wide KV blocks, so the key-position
-    vector is split across the grid."""
+    """The head-group causal attention program (one query head per grid
+    step) at one and 256 query rows over a 1024-long cache: eight
+    128-wide KV blocks, so the key-position vector is split across the
+    grid.  Prefill buckets run it; decode folds the group (below)."""
     sk = 1024
     kern = LY._attention_kernel(sq, D_HEAD, sk, D_HEAD, GROUP, True,
                                 D_HEAD ** -0.5, chip_options)
@@ -115,6 +116,26 @@ def test_causal_gqa_attention_compiles_for_v5e(sq, one_chip, chip_options):
     hlo = _compile_for_chip(kern, {
         "Q": (GROUP, sq, D_HEAD), "KT": (sk, D_HEAD), "VT": (D_HEAD, sk),
         "QP": (sq,), "KP": (sk,)}, one_chip)
+    _assert_chip_kernel(kern, hlo, "attention")
+
+
+@pytest.mark.parametrize("group,sk,dh", [(3, 2048, 64), (7, 1024, 128),
+                                         (7, 32768, 128)])
+def test_folded_decode_attention_compiles_for_v5e(group, sk, dh, one_chip,
+                                                  chip_options):
+    """Decode attention as the layers run it: the GQA group folded into
+    the query rows (M 3 for smollm-135m, 7 for qwen2-7b) and the cache
+    as one N tile where it fits the VMEM budget (2048 x 64, 1024 x 128),
+    else split (32768 x 128): Mosaic accepts the whole-cache tiles."""
+    kern, rows, kgroup = LY._attention_call(
+        (1, group, 1, dh), (1, 1, sk, dh), dh, True, dh ** -0.5,
+        chip_options)
+    assert (rows, kgroup) == (group, 1)
+    assert kern.blocks["N"] == LY._kv_tile(sk, dh, dh)
+    assert (kern.dims["N"] == 1) == (sk <= 2048)
+    hlo = _compile_for_chip(kern, {
+        "Q": (group, dh), "KT": (sk, dh), "VT": (dh, sk),
+        "QP": (group,), "KP": (sk,)}, one_chip)
     _assert_chip_kernel(kern, hlo, "attention")
 
 
