@@ -1,9 +1,9 @@
 """The check that decides ``correct``.
 
 Once the window has closed, a sample of the requests it finished, drawn
-from the seed and always holding the longest, is run through the plain
-reference (``reference.py``), each prompt with its served tokens in one
-pass.  For every served token, the gap is how far its reference logit
+from the seed and always holding the longest, is run through the
+configuration's plain reference (``references/<family>.py``, which the
+caller passes in), each prompt with its served tokens in one pass.  For every served token, the gap is how far its reference logit
 lies below the reference's best logit at that position: 0 where the
 served greedy token is the reference's own choice.  The widest gap over
 the sample is compared with the cell's limit (``limits/<cell>.json``).
@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, traffic
+from bench import traffic
 
 
 @jax.jit
@@ -56,10 +56,13 @@ def sample(finished: list, seed: int, tokens: int) -> list:
     return out
 
 
-def widest_gaps(model: dict, params, reqs: list, control: bool = False
+def widest_gaps(reference, model: dict, params, reqs: list,
+                control: bool = False
                 ) -> Tuple[float, Optional[float], int]:
     """(widest gap of the served tokens, widest gap of the control's
-    tokens or None, tokens compared) over ``reqs``."""
+    tokens or None, tokens compared) over ``reqs``, by ``reference``, the
+    module of the family that the configuration names, on its ``model``
+    block."""
     gap, gap_c, n = 0.0, None, 0
     for r in reqs:
         seq = list(r.prompt) + list(r.tokens[:-1])

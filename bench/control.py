@@ -7,13 +7,13 @@ One process builds and warms the cell's engine once, then for each seed
 draws that seed's weights, serves a short window at the cell's own load
 through ``Engine.run``, and compares a sample of the finished requests
 with the plain reference as a benchmark run does.  For the first
-``--control-seeds`` seeds it also reads the control: the reference
-computed with fp8 matmul operands (``reference.py``), put in the
-program's place.  Both readings go through the benchmark's own decision
-(``harness.numbers`` and ``harness.passes``): the program's has to come
-out correct, the control's not.  One JSON line per seed.  The
-benchmark's own runs never run the control.  Needs a TPU, as ``run.py``
-does.
+``--control-seeds`` seeds it also reads the control: the family's
+reference (``references/<family>.py``) computed with fp8 matmul
+operands, put in the program's place.  Both readings go through the
+benchmark's own decision (``harness.numbers`` and ``harness.passes``):
+the program's has to come out correct, the control's not.  One JSON
+line per seed.  The benchmark's own runs never run the control.  Needs
+a TPU, as ``run.py`` does.
 """
 
 import argparse
@@ -37,8 +37,8 @@ def readings(engine, cell, like, seed: int, seconds: float, control: bool):
     w = harness.serve(engine, cell, seed, seconds)
     picked = check.sample(harness.checked(w), seed,
                           harness.check_tokens(cell))
-    gap, gap_c, n = check.widest_gaps(cell.config["model"], engine.params,
-                                      picked, control=control)
+    gap, gap_c, n = check.widest_gaps(cell.reference, cell.config["model"],
+                                      engine.params, picked, control=control)
     nums = harness.numbers(cell, w, gap, n)
     out = {"seed": seed, "widest_gap": gap, "tokens_compared": n,
            "requests_compared": len(picked),

@@ -11,13 +11,46 @@ harness has no loop of its own around the model.
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is in a file of its own, found by the name ``BENCHMARK.json``
 gives it: ``configs/<config>.json``, ``traffic/<mix>.json``,
-``metrics/<metric>.py``, ``work/<family>.py`` and
-``limits/<cell>.json``.
+``metrics/<metric>.py`` and ``limits/<cell>.json``.
+
+A model family comes in as files of its own too, found by the
+configuration file's ``work`` key, ``<family>``:
+
+* ``work/<family>.py``, the work the family needs, counted from the
+  ``model`` block's shapes, for the per-layer readers:
+  ``decode_kernel_calls(m, contexts)`` and ``prefill_kernel_calls(m, p)``
+  (lists of ``(flops, bytes)`` kernel calls), ``least_time(calls,
+  peak)``, ``decode_flops(m, context)`` and ``prefill_flops(m, p)``;
+  ``phases.py`` also reads ``decode_attention_call(m, contexts)`` and
+  ``swiglu_call(m, tokens)`` where a family has those kernels;
+* ``references/<family>.py``, the plain float32 reference that decides
+  ``correct``: ``logits(model, params, tokens, quant=False)`` gives the
+  float32 logits of one token sequence (rows past it padding), reading
+  the published ``model`` block and the benchmark's seeded weights by
+  leaf name and importing nothing of the program; ``quant=True`` gives
+  the fp8 control.
+
+A configuration file's ``model`` block is the published configuration
+as it is run.  It maps to the program's ``ModelConfig`` by
+``model_config``: the published keys of ``MAPPED`` give their fields;
+the optional ``program`` block, ``{<ModelConfig field>: value}``, gives
+the sizes the program names differently from the checkpoint (experts,
+experts per token, expert width, shared experts, leading dense layers,
+LoRA ranks, qk-norm, window) and any mapped field whose key the source
+does not publish; ``head_dim`` alone has a default, ``hidden_size //
+num_attention_heads``.  Every other published key is listed, with its
+reason, in the file's ``not_mapped`` block (a key whose value the
+``program`` block restates under the program's name is listed there
+too).  A key or field that is none of these, a mapped field given
+twice, or one given nowhere stops the run with a ValueError before
+anything compiles: nothing is dropped in silence.  The registry entry
+named by ``arch`` gives only what the file does not state.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import itertools
 import json
@@ -33,6 +66,8 @@ import numpy as np
 BENCH = Path(__file__).resolve().parent
 CHECKOUT = BENCH.parent
 CACHE = BENCH / ".cache"
+WORK = BENCH / "work"               # work/<family>.py
+REFERENCES = BENCH / "references"   # references/<family>.py
 CHECK_TOKENS = 300      # served tokens the check compares, at least
 
 
@@ -53,6 +88,24 @@ def load_module(path: Path):
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def _family_file(path: Path):
+    if not path.exists():
+        raise FileNotFoundError(f"no {path.parent.name}/{path.name} for "
+                                f"the family {path.stem!r}")
+    return load_module(path)
+
+
+def work_of(config: dict):
+    """The family's work counts, ``work/<family>.py``."""
+    return _family_file(WORK / f"{config['work']}.py")
+
+
+def reference_of(config: dict):
+    """The family's plain reference, ``references/<family>.py``."""
+    return _family_file(REFERENCES / f"{config['work']}.py")
+
+
 @dataclass
 class Cell:
     name: str
@@ -69,7 +122,11 @@ class Cell:
 
     @property
     def work(self):
-        return load_module(BENCH / "work" / f"{self.config['work']}.py")
+        return work_of(self.config)
+
+    @property
+    def reference(self):
+        return reference_of(self.config)
 
 
 def _reports(metric: dict, cell: str, e2e_here: set) -> bool:
@@ -94,7 +151,10 @@ def load_cell(name: str, spec_path: Path = CHECKOUT / "BENCHMARK.json"
     e2e = [m for m in spec["end_to_end"] if _reports(m, name, set())]
     here = {m["name"] for m in e2e}
     per = [m for m in spec["per_layer"] if _reports(m, name, here)]
-    return Cell(name=name, config=load_json(CHECKOUT / cfg["file"]),
+    config = load_json(CHECKOUT / cfg["file"])
+    work_of(config)         # a family without its work counts or its
+    reference_of(config)    # reference stops here, before serving
+    return Cell(name=name, config=config,
                 mix=traffic.load_mix(w["traffic"]), chips=int(w["chips"]),
                 limits=load_json(BENCH / "limits" / f"{name}.json"),
                 end_to_end=e2e, per_layer=per)
@@ -117,25 +177,63 @@ def configure_caches() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
+# published key -> ModelConfig field
+MAPPED = {
+    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "d_head", "intermediate_size": "d_ff",
+    "vocab_size": "vocab", "max_position_embeddings": "max_seq",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "attention_bias": "qkv_bias", "tie_word_embeddings": "tie_embeddings",
+    "torch_dtype": "dtype",
+}
+
+
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file (see the
+    module's docstring).  Raises ValueError, naming the key or field,
+    where the file leaves a published key or a mapped field unaccounted
+    for, gives a field twice, or names a field ModelConfig lacks."""
     import jax.numpy as jnp
 
     from repro import configs
+    from repro.models.common import ModelConfig
 
-    m = config["model"]
-    return dataclasses.replace(
-        configs.get_config(config["arch"]),
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"],
-        d_head=m["hidden_size"] // m["num_attention_heads"],
-        d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-        max_seq=m["max_position_embeddings"],
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        qkv_bias=bool(m["attention_bias"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        dtype=getattr(jnp, m["torch_dtype"]))
+    m, name = config["model"], config.get("name", "?")
+    program = config.get("program", {})
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    for fld in program:
+        if fld not in fields:
+            raise ValueError(f"{name}: program field {fld!r} is not a "
+                             "field of ModelConfig")
+    values = dict(program)
+    for key, fld in MAPPED.items():
+        if key in m:
+            if fld in program:
+                raise ValueError(f"{name}: program field {fld!r} is already "
+                                 f"set by the published key {key!r}")
+            values[fld] = m[key]
+        elif fld not in program:
+            if key != "head_dim":
+                raise ValueError(f"{name}: the published key {key!r} is "
+                                 "missing and the program block sets no "
+                                 f"{fld!r}")
+            values[fld] = values["d_model"] // values["n_heads"]
+    values.update(rope_theta=float(values["rope_theta"]),
+                  norm_eps=float(values["norm_eps"]),
+                  qkv_bias=bool(values["qkv_bias"]),
+                  tie_embeddings=bool(values["tie_embeddings"]),
+                  dtype=getattr(jnp, values["dtype"]))
+    not_mapped = set(config.get("not_mapped", {}))
+    wrong = sorted(not_mapped & set(MAPPED) | not_mapped - set(m))
+    if wrong:
+        raise ValueError(f"{name}: not_mapped lists {wrong}, which are "
+                         "mapped or not published keys")
+    stray = sorted(set(m) - set(MAPPED) - not_mapped)
+    if stray:
+        raise ValueError(f"{name}: published keys {stray} are neither "
+                         "mapped nor listed under not_mapped")
+    return dataclasses.replace(configs.get_config(config["arch"]), **values)
 
 
 def build_engine(config: dict, mix: dict, seed: int):
@@ -564,8 +662,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
     picked = check.sample(checked(w), seed, check_tokens(cell))
     t_check = time.perf_counter()
-    gap, gap_c, compared = check.widest_gaps(m, params, picked,
-                                             control=control)
+    gap, gap_c, compared = check.widest_gaps(
+        cell.reference, cell.config["model"], params, picked,
+        control=control)
     nums = numbers(cell, w, gap_c if control else gap, compared)
     result["correct"] = passes(nums)
     result["metrics"] = metrics

@@ -1,14 +1,18 @@
-"""Plain float32 reference of the served decoder, and its fp8 control.
+"""Plain float32 reference of the dense family, and its fp8 control.
 
 A straightforward ``jax.numpy`` forward pass of a Llama/Qwen2-style
 decoder as published: token embedding; per layer RMSNorm, Q/K/V
-projections (with bias where the configuration has it), rotary
+projections (with bias where ``attention_bias`` says so, and for
+``Qwen2ForCausalLM``, which projects them with bias and publishes no
+such key), rotary
 position embedding (rotate-half form, base ``rope_theta``), causal
 grouped-query softmax attention, output projection and residual; then
 RMSNorm, gated SwiGLU MLP and residual; a final RMSNorm and the head
 (the embedding transposed when tied).  No kernel, cache, batching or
 bucketing of the program is used, and nothing of the program is
-imported: it reads the weight tree by its leaf names only.
+imported: it reads the weight tree by its leaf names only.  Heads are
+``head_dim`` wide where the configuration gives one, else
+``hidden_size / num_attention_heads`` (``head_dim``).
 
 It runs in float32 at matmul precision "highest", one layer per jitted
 call (the stacked layer weights are indexed inside the call, so one
@@ -94,16 +98,22 @@ def _logits(x, ln_f, head, dims, quant):
     return _mm(_rms(x, ln_f, dict(dims)["eps"]), head, quant)
 
 
+def head_dim(model: dict) -> int:
+    return model.get("head_dim", model["hidden_size"]
+                     // model["num_attention_heads"])
+
+
 def model_dims(model: dict) -> tuple:
     """The hashable dims the jitted layer needs, from a config file's
     ``model`` block."""
+    qwen2 = "Qwen2ForCausalLM" in model.get("architectures", ())
     return tuple(sorted({
         "n_heads": model["num_attention_heads"],
         "n_kv_heads": model["num_key_value_heads"],
-        "d_head": model["hidden_size"] // model["num_attention_heads"],
+        "d_head": head_dim(model),
         "eps": float(model["rms_norm_eps"]),
         "theta": float(model["rope_theta"]),
-        "qkv_bias": bool(model["attention_bias"]),
+        "qkv_bias": bool(model.get("attention_bias", qwen2)),
     }.items()))
 
 

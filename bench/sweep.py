@@ -9,8 +9,10 @@ through ``Engine.run`` as a benchmark run does.  For each rate it prints
 one JSON line: the requests due, those admitted, the backlog (due and
 not yet admitted) at the window's middle and at its close, and the
 median and 90th percentile of the time to first token.  The knee is the
-highest rate whose backlog does not grow from the middle to the close;
-a cell's mix file runs at 0.8 of it.  Needs a TPU, as ``run.py`` does.
+highest rate at which no backlog holds at the middle or at the close
+(a backlog that drains by the close is a queue the rate built, not one
+it sustains); a cell's mix file runs at 0.8 of it.  Needs a TPU, as
+``run.py`` does.
 """
 
 import argparse

@@ -68,8 +68,8 @@ def served(cell, seconds=1.5):
 def test_float32_engine_agrees_with_reference():
     cell = tiny_cell("float32", tied=True)
     engine, picked = served(cell)
-    gap, _, n = check.widest_gaps(cell.config["model"], engine.params,
-                                  picked)
+    gap, _, n = check.widest_gaps(cell.reference, cell.config["model"],
+                                  engine.params, picked)
     assert n >= 100
     assert gap < 1e-4
 

@@ -1,15 +1,17 @@
 """Seeded weights, made on the device in one jitted call.
 
 The benchmark draws every weight itself, so that the plain reference
-(``reference.py``) takes nothing the program made.  The tree has the
-layout of the served model's parameters (its leaf names and shapes);
-the values come from ``--seed`` alone:
+(``references/<family>.py``) takes nothing the program made.  The tree
+has the layout of the served model's parameters (its leaf names and
+shapes); the values come from ``--seed`` alone:
 
 * matrices: normal, scaled by 1/sqrt(fan-in), fan-in being the
   second-to-last axis, and the model width for the embedding (which
   the tied head reads transposed), so logits have unit scale;
-* norm gains (``ln1``, ``ln2``, ``ln_f``): 1 + 0.1 * normal, so a kernel
-  that drops or misplaces a gain shows;
+* norm gains, by name: every leaf named ``ln*`` (``ln1``, ``ln2``,
+  ``ln_f``) or ``*norm`` (``q_norm``, ``k_norm``, ``kv_norm``,
+  ``ssm_norm``): 1 + 0.1 * normal, so a kernel that drops or misplaces
+  a gain shows;
 * biases (``bq``, ``bk``, ``bv``): 0.1 * normal.
 """
 
@@ -18,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-GAINS = ("ln1", "ln2", "ln_f")
 BIASES = ("bq", "bk", "bv")
 
 
@@ -30,9 +31,13 @@ def key_of(seed: int) -> jax.Array:
                               (seed >> 32) & 0xFFFFFFFF)
 
 
+def is_gain(name: str) -> bool:
+    return name.startswith("ln") or name.endswith("norm")
+
+
 def _leaf(key, path, shape, dtype):
     name = str(getattr(path[-1], "key", path[-1]))
-    if name in GAINS:
+    if is_gain(name):
         return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
                 ).astype(dtype)
     if name in BIASES:

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from typing import Iterable, List, Tuple
 
+from bench.references.dense import head_dim
+
 Call = Tuple[float, float]
 BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -21,7 +23,7 @@ BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 def dims(m: dict):
     d = m["hidden_size"]
     h, hkv = m["num_attention_heads"], m["num_key_value_heads"]
-    return d, h, hkv, d // h, m["intermediate_size"], m["vocab_size"]
+    return d, h, hkv, head_dim(m), m["intermediate_size"], m["vocab_size"]
 
 
 def _elt(m: dict) -> int:
