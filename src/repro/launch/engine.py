@@ -189,6 +189,11 @@ class ServeReport:
                                  # at doubled cool-down)
     decode_backend: str = ""     # the rung decode ended the run on
                                  # (e.g. "pipeline-pallas" when healed)
+    # bytes each compiled program aliases from the KV cache it is given
+    # over the cache's bytes, read at warm-up: 1.0 when the program
+    # updates the cache in place, 0.0 when it writes a new one
+    # ({"decode": ..., "insert": ...})
+    cache_alias_share: Dict[str, float] = field(default_factory=dict)
     # structured failure records: {"rid", "reason", "step", ...} — one
     # per poison eviction / deadline / queue_full rejection / watchdog
     # demotion, so a failed request is triageable, not just a counter
@@ -357,7 +362,10 @@ class Engine:
         m, L = self.model, self.max_len
         # a profiler trace shows these programs under their module names,
         # jit__lambda, jit_decode_step and jit_insert: the benchmark's
-        # trace reduction finds them by those names (bench/trace.py)
+        # trace reduction finds them by those names (bench/trace.py).
+        # Insert takes the batched KV cache as a donation and writes its
+        # slot in place.  Decode writes only its new rows too, but into
+        # a copy: its input cache stays valid after the call
         self._prefill = jax.jit(lambda p, t: m.prefill(p, t, max_len=L))
         self._decode = jax.jit(m.decode_step)
 
@@ -368,7 +376,7 @@ class Engine:
                 lambda b, s: jax.lax.dynamic_update_slice_in_dim(
                     b, s.astype(b.dtype), slot, axis=1), batched, one)
 
-        self._insert = jax.jit(insert)
+        self._insert = jax.jit(insert, donate_argnums=(0,))
 
         self.caches = m.init_cache(self.max_batch, self.max_len)
         self.slots: List[Optional[_Slot]] = [None] * self.max_batch
@@ -382,6 +390,7 @@ class Engine:
         self.watchdog_demotions = 0  # tick-level decode demotions
         self.demotion_compiles = 0   # compiles explained by demotions
                                      # (excluded from decode_recompiles)
+        self.cache_alias_share: Dict[str, float] = {}
 
     # -- scheduling helpers -------------------------------------------------
     def _bucket(self, plen: int) -> Optional[int]:
@@ -430,11 +439,22 @@ class Engine:
                 lg, cache = self._prefill(self.params, toks)
                 self.caches = self._insert(self.caches, cache, 0)
                 lg.block_until_ready()
-            lg, self.caches = self._decode(
-                self.params, self.caches,
-                jnp.zeros((self.max_batch, 1), jnp.int32),
-                jnp.zeros((self.max_batch,), jnp.int32))
+            step_args = (jnp.zeros((self.max_batch, 1), jnp.int32),
+                         jnp.zeros((self.max_batch,), jnp.int32))
+            lg, self.caches = self._decode(self.params, self.caches,
+                                           *step_args)
             lg.block_until_ready()
+            # the executables just compiled, found again by their
+            # arguments (no compile): how much of the cache each aliases
+            nbytes = sum(a.nbytes for a in self._jax.tree.leaves(
+                self.caches))
+            self.cache_alias_share = {
+                name: lowered.compile().memory_analysis()
+                .alias_size_in_bytes / nbytes
+                for name, lowered in (
+                    ("decode", self._decode.lower(
+                        self.params, self.caches, *step_args)),
+                    ("insert", self._insert.lower(self.caches, cache, 0)))}
         self.pallas_fallbacks = sum(
             1 for w in caught if "pallas lowering fallback" in str(w.message))
         # the decode warm-up wrote garbage at position 0 of every slot;
@@ -688,6 +708,7 @@ class Engine:
         report.probes = self.probes
         report.probe_failures = self.probe_failures
         report.decode_backend = _backend_label(self.cfg)
+        report.cache_alias_share = dict(self.cache_alias_share)
         if self.strict_no_recompile and report.decode_recompiles:
             raise RuntimeError(
                 f"{report.decode_recompiles} pipeline recompiles after "

@@ -237,25 +237,30 @@ class EncDec:
         return constrain(logits, "batch", None, "tensor"), caches
 
     def decode_step(self, params, caches, tokens, pos):
+        """One decoder token.  The stacked self- and cross-attention
+        caches ride through the layer scan in the carry (as in
+        ``LM.decode_step``): a layer writes its new self-attention rows
+        and reads its cross keys/values, and the step returns the same
+        buffers, updated in place when the caller donates them."""
         cfg = self.cfg
-        b = tokens.shape[0]
         x = params["embed"][tokens].astype(cfg.dtype)
         x = x + _sinusoid_at(pos, cfg.d_model).astype(cfg.dtype)
 
-        def body(x, inp):
-            lp, cache = inp
+        def body(carry, inp):
+            x, cache = carry
+            lp, layer = inp
             xn = layer_norm(x, lp["ln1_g"], lp["ln1_b"], cfg.norm_eps)
             y, new_self = L.attention_decode(lp["attn"], xn, cache["self"],
-                                             pos, cfg)
+                                             layer, pos, cfg)
             x = x + y
-            x = x + _attn_block(lp, x, cfg, "ln_x", causal=False,
-                                kv=cache["cross"])
+            cross = jax.tree.map(lambda a: a[layer], cache["cross"])
+            x = x + _attn_block(lp, x, cfg, "ln_x", causal=False, kv=cross)
             x = x + _mlp(lp, x, cfg)
-            return x, {"self": new_self, "cross": cache["cross"]}
+            return (x, {"self": new_self, "cross": cache["cross"]}), None
 
-        x, new_caches = jax.lax.scan(body, x, (params["dec"], caches),
-                                     unroll=cfg.n_layers if cfg.unroll_scans
-                                     else 1)
+        (x, new_caches), _ = jax.lax.scan(
+            body, (x, caches), (params["dec"], jnp.arange(cfg.n_layers)),
+            unroll=cfg.n_layers if cfg.unroll_scans else 1)
         x = layer_norm(x, params["ln_f_g"], params["ln_f_b"], cfg.norm_eps)
         logits = x @ params["embed"].T
         return constrain(logits, "batch", None, "tensor"), new_caches

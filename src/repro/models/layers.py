@@ -3,7 +3,8 @@
 Every layer is a pair of functions:
   * ``init_<layer>(pb, cfg)``          — adds params+specs to a ParamBuilder
   * ``<layer>_apply(p, x, cfg, ...)``  — forward (full sequence)
-  * ``<layer>_decode(p, x, cache, ...)`` — one-token step with cache
+  * ``<layer>_decode(p, x, cache, layer, ...)`` — one-token step: reads
+    and writes layer ``layer`` of its stage's stacked cache
 
 The paper's fused kernels are wired in here: attention uses the fused
 flash kernel (Example 1), gated MLPs use Flash-RMSNorm+FFN-SwiGLU
@@ -309,9 +310,45 @@ def attention_cache_specs(cfg: ModelConfig):
             "v": ("batch", "tensor", "kv_seq", None)}
 
 
-def attention_decode(p, x, cache, pos, cfg: ModelConfig):
-    """One-token decode: insert k/v at ``pos``, attend over the cache.
+def _write_rows(buf, rows, layer, pos, axis: int):
+    """Write one new row per sequence into layer ``layer`` of a stacked
+    cache leaf, and nothing else.
 
+    ``buf`` is ``(n_layers, b, ...)``; ``rows`` has the shape of one
+    layer's leaf with 1 on the position ``axis`` (of that leaf);
+    ``pos`` is a scalar or a ``(b,)`` vector.  One scatter into ``buf``
+    that indexes every axis up to the position axis and takes the axes
+    after it as its window, so it is already in the form XLA emits
+    without transposing or relaying out the cache: a caller that
+    donates the cache updates it in place.  Sharded, the partitioner
+    moves only the rows and indices between devices, never the cache
+    (batching dimensions for batch and heads would transpose it)."""
+    lead = rows.shape[:axis]                      # (b, ...) before pos
+    b = lead[0]
+    at = [jnp.full(lead, layer, jnp.int32)]
+    at += jnp.meshgrid(*[jnp.arange(n, dtype=jnp.int32) for n in lead],
+                       indexing="ij")
+    at.append(jnp.broadcast_to(
+        jnp.broadcast_to(pos, (b,)).reshape((b,) + (1,) * (axis - 1)), lead))
+    idx = jnp.stack(at, axis=-1).reshape(-1, axis + 2)
+    upd = jnp.squeeze(rows, axis).reshape((-1,) + rows.shape[axis + 1:])
+    put = tuple(range(axis + 2))
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(1, upd.ndim)),
+        inserted_window_dims=put, scatter_dims_to_operand_dims=put)
+    return jax.lax.scatter(buf, idx, upd.astype(buf.dtype), dims,
+                           indices_are_sorted=True, unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.CLIP)
+
+
+def attention_decode(p, x, cache, layer, pos, cfg: ModelConfig):
+    """One-token decode: write k/v at ``pos`` into layer ``layer`` of the
+    stacked cache, attend over that layer.
+
+    ``cache`` leaves are ``(n_layers, b, hkv, max_len, dh)``, the whole
+    stage's stack carried through the layer scan: the step writes its
+    ``(b, hkv, 1, dh)`` rows and returns the same stack, and attention
+    reads ``cache[layer]`` where its operands are formed.
     ``pos`` is either a scalar (every sequence at the same position — the
     classic lockstep batch) or a ``(b,)`` int vector (ragged
     continuous-batching step: each sequence writes its k/v at its own
@@ -327,16 +364,9 @@ def attention_decode(p, x, cache, pos, cfg: ModelConfig):
     else:
         positions = None
     q, k, v = _qkv(p, x, cfg, positions)
-    if ragged:
-        def put(buf, val, pv):  # per sequence: (hkv, max_len, dh) at pv
-            return jax.lax.dynamic_update_slice(buf, val, (0, pv, 0))
-        ck = jax.vmap(put)(cache["k"], k.astype(cache["k"].dtype), pos)
-        cv = jax.vmap(put)(cache["v"], v.astype(cache["v"].dtype), pos)
-    else:
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, pos, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, pos, 0))
+    cache = {"k": _write_rows(cache["k"], k, layer, pos, 2),
+             "v": _write_rows(cache["v"], v, layer, pos, 2)}
+    ck, cv = cache["k"][layer], cache["v"][layer]
     # mask positions beyond pos via the causal path with explicit offset
     if cfg.attn_impl == "pipeline":
         o = _attention_pipeline(q, ck, cv, 1.0 / cfg.d_head ** 0.5,
@@ -346,7 +376,7 @@ def attention_decode(p, x, cache, pos, cfg: ModelConfig):
                               impl=cfg.attn_impl,
                               unroll=cfg.unroll_scans)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.d_head)
-    return constrain(o @ p["wo"], "batch", None, None), {"k": ck, "v": cv}
+    return constrain(o @ p["wo"], "batch", None, None), cache
 
 
 # ---------------------------------------------------------------------------
@@ -430,12 +460,15 @@ def mla_cache_specs(cfg: ModelConfig):
             "krope": ("batch", "kv_seq", None)}
 
 
-def mla_decode(p, x, cache, pos, cfg: ModelConfig):
+def mla_decode(p, x, cache, layer, pos, cfg: ModelConfig):
     """Absorbed decode: attention runs against the *compressed* cache
     (this is MLA's serving trick; the per-token cache is r+rope wide).
 
-    Like ``attention_decode``, ``pos`` is a scalar or a ``(b,)`` vector
-    (ragged continuous-batching step)."""
+    Like ``attention_decode``, ``cache`` is the stage's stacked cache
+    (``(n_layers, b, max_len, width)`` leaves), of which the step writes
+    its ``ckv``/``krope`` rows into layer ``layer`` and reads that layer;
+    ``pos`` is a scalar or a ``(b,)`` vector (ragged continuous-batching
+    step)."""
     b = x.shape[0]
     h = cfg.n_heads
     pos = jnp.asarray(pos, jnp.int32)
@@ -443,19 +476,9 @@ def mla_decode(p, x, cache, pos, cfg: ModelConfig):
     positions = pos[:, None, None] if ragged else pos.reshape(1)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)       # (b,h,1,*)
     ckv_t, krope_t = _mla_kv_compressed(p, x, cfg, positions)
-    if ragged:
-        def put(buf, val, pv):  # per sequence: (max_len, width) at pv
-            return jax.lax.dynamic_update_slice(buf, val, (pv, 0))
-        ckv = jax.vmap(put)(cache["ckv"],
-                            ckv_t.astype(cache["ckv"].dtype), pos)
-        krope = jax.vmap(put)(cache["krope"],
-                              krope_t.astype(cache["krope"].dtype), pos)
-    else:
-        ckv = jax.lax.dynamic_update_slice(
-            cache["ckv"], ckv_t.astype(cache["ckv"].dtype), (0, pos, 0))
-        krope = jax.lax.dynamic_update_slice(
-            cache["krope"], krope_t.astype(cache["krope"].dtype),
-            (0, pos, 0))
+    cache = {"ckv": _write_rows(cache["ckv"], ckv_t, layer, pos, 1),
+             "krope": _write_rows(cache["krope"], krope_t, layer, pos, 1)}
+    ckv, krope = cache["ckv"][layer], cache["krope"][layer]
 
     wkv_b = p["wkv_b"].reshape(cfg.kv_lora_rank, h,
                                cfg.qk_nope_dim + cfg.v_head_dim)
@@ -477,8 +500,7 @@ def mla_decode(p, x, cache, pos, cfg: ModelConfig):
     o = jnp.einsum("bhqr,rhv->bhqv", ctx, w_uv.astype(jnp.float32))
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * cfg.v_head_dim)
     o = o.astype(x.dtype)
-    return (constrain(o @ p["wo"], "batch", None, None),
-            {"ckv": ckv, "krope": krope})
+    return constrain(o @ p["wo"], "batch", None, None), cache
 
 
 # ---------------------------------------------------------------------------
@@ -858,14 +880,16 @@ def mamba_prefill(p, x, gamma, cfg: ModelConfig):
     return constrain(y @ p["w_out"], "batch", None, None), cache
 
 
-def mamba_decode(p, x, gamma, cache, cfg: ModelConfig):
-    """One-token SSM step: O(1) state update (no KV cache)."""
+def mamba_decode(p, x, gamma, cache, layer, cfg: ModelConfig):
+    """One-token SSM step: O(1) state update (no KV cache).  ``cache`` is
+    the stage's stacked state; the step reads layer ``layer`` and writes
+    that layer's whole (small, position-free) state back."""
     b = x.shape[0]
     nh, hd, n, di = (cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                      cfg.d_inner)
     xn = rms_norm(x, gamma, cfg.norm_eps)
     z, xbc, dt = _mamba_proj(p, xn, cfg)                  # x: (b,1,d)
-    window = jnp.concatenate([cache["conv"], xbc], axis=1)
+    window = jnp.concatenate([cache["conv"][layer], xbc], axis=1)
     w = p["conv_w"]
     conv = jax.nn.silu((window * w[None]).sum(axis=1, keepdims=True)
                        + p["conv_b"])
@@ -877,11 +901,17 @@ def mamba_decode(p, x, gamma, cache, cfg: ModelConfig):
     decay = jnp.exp(dtv * A[None])                        # (b,nh)
     Bv = B[:, 0].astype(jnp.float32)                      # (b,n)
     Cv = C[:, 0].astype(jnp.float32)
-    h = cache["h"] * decay[:, :, None, None] + jnp.einsum(
+    h = cache["h"][layer] * decay[:, :, None, None] + jnp.einsum(
         "bh,bhd,bn->bhdn", dtv, xh, Bv)
-    y = jnp.einsum("bhdn,bn->bhd", h, Cv)
+    put = functools.partial(jax.lax.dynamic_update_index_in_dim,
+                            index=layer, axis=0)
+    cache = {"h": put(cache["h"], h),
+             "conv": put(cache["conv"], window[:, 1:])}
+    # the output reads the new state back after the write, so no read of
+    # the old state is left to outlive the in-place update (XLA would
+    # copy the whole stack to keep one)
+    y = jnp.einsum("bhdn,bn->bhd", cache["h"][layer], Cv)
     y = y + xh * p["D"].astype(jnp.float32)[None, :, None]
     y = y.reshape(b, 1, di).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["ssm_norm"], cfg.norm_eps)
-    new_cache = {"h": h, "conv": window[:, 1:]}
-    return constrain(y @ p["w_out"], "batch", None, None), new_cache
+    return constrain(y @ p["w_out"], "batch", None, None), cache

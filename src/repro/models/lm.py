@@ -134,17 +134,22 @@ def _sub_cache_specs(cfg, spec: SubLayer):
     return {}
 
 
-def _decode_sublayer(p, x, cache, pos, cfg, spec: SubLayer):
+def _decode_sublayer(p, x, cache, layer, pos, cfg, spec: SubLayer):
+    """One sub-layer's decode step against its stacked cache: the mixer
+    reads and writes layer ``layer`` of it and returns the whole stack."""
     if spec.mixer == "attn":
         y, cache = L.attention_decode(
-            p["mixer"], rms_norm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg)
+            p["mixer"], rms_norm(x, p["ln1"], cfg.norm_eps), cache, layer,
+            pos, cfg)
         x = x + y
     elif spec.mixer == "mla":
         y, cache = L.mla_decode(
-            p["mixer"], rms_norm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg)
+            p["mixer"], rms_norm(x, p["ln1"], cfg.norm_eps), cache, layer,
+            pos, cfg)
         x = x + y
     elif spec.mixer == "mamba":
-        y, cache = L.mamba_decode(p["mixer"], x, p["ln1"], cache, cfg)
+        y, cache = L.mamba_decode(p["mixer"], x, p["ln1"], cache, layer,
+                                  cfg)
         x = x + y
     if spec.mlp == "swiglu":
         x = x + L.rmsnorm_swiglu_apply(p["mlp"], x, p["ln2"], cfg)
@@ -245,22 +250,31 @@ class LM:
     def decode_step(self, params, caches, tokens, pos):
         """tokens: (B, 1) next input token; pos: filled cache length —
         a scalar (lockstep batch) or a (B,) int vector (ragged
-        continuous-batching step, each sequence at its own position)."""
+        continuous-batching step, each sequence at its own position).
+
+        Each stage's stacked cache rides through the layer scan in the
+        carry, with the layer index as the scanned input: a layer writes
+        only its new rows (a Mamba layer its small state) and the step
+        returns the same buffers, so a caller that donates ``caches``
+        (``jax.jit(..., donate_argnums=(1,))``) updates them in place."""
         cfg = self.cfg
         x = self._embed(params, tokens)
 
         new_caches = {}
         for si, stage in enumerate(self.stages):
-            def body(x, inp, stage=stage):
-                lp, cache = inp
-                new = {}
+            def body(carry, inp, stage=stage):
+                x, cache = carry
+                lp, layer = inp
+                cache = dict(cache)
                 for j, spec in enumerate(stage.subs):
-                    x, new[f"sub{j}"] = _decode_sublayer(
-                        lp[f"sub{j}"], x, cache[f"sub{j}"], pos, cfg, spec)
-                return x, new
+                    x, cache[f"sub{j}"] = _decode_sublayer(
+                        lp[f"sub{j}"], x, cache[f"sub{j}"], layer, pos, cfg,
+                        spec)
+                return (x, cache), None
 
-            x, new_caches[f"stage{si}"] = jax.lax.scan(
-                body, x, (params[f"stage{si}"], caches[f"stage{si}"]),
+            (x, new_caches[f"stage{si}"]), _ = jax.lax.scan(
+                body, (x, caches[f"stage{si}"]),
+                (params[f"stage{si}"], jnp.arange(stage.n)),
                 unroll=stage.n if cfg.unroll_scans else 1)
         return self._logits(params, x), new_caches
 

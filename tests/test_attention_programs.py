@@ -119,10 +119,13 @@ def test_prefill_decode_parity_through_pipeline(grouped, tmp_path,
                           jnp.float32)
 
     prefill = L.attention_apply(p, x, cfg, causal=True)
-    cache = L.attention_init_cache(cfg, batch, seq, jnp.float32)
+    # a one-layer stack: decode reads and writes layer 0 of it
+    cache = jax.tree.map(lambda a: a[None],
+                         L.attention_init_cache(cfg, batch, seq,
+                                                jnp.float32))
     for pos in range(seq):
-        step, cache = L.attention_decode(p, x[:, pos:pos + 1], cache, pos,
-                                         cfg)
+        step, cache = L.attention_decode(p, x[:, pos:pos + 1], cache, 0,
+                                         pos, cfg)
         np.testing.assert_allclose(np.asarray(step[:, 0]),
                                    np.asarray(prefill[:, pos]),
                                    rtol=2e-5, atol=2e-5)

@@ -162,10 +162,13 @@ def test_prefill_decode_parity_at_huge_logits(tmp_path, monkeypatch):
 
     prefill = L.attention_apply(p, x, cfg, causal=True)
     assert np.isfinite(np.asarray(prefill)).all()
-    cache_kv = L.attention_init_cache(cfg, batch, seq, jnp.float32)
+    # a one-layer stack: decode reads and writes layer 0 of it
+    cache_kv = jax.tree.map(lambda a: a[None],
+                            L.attention_init_cache(cfg, batch, seq,
+                                                   jnp.float32))
     for pos in range(seq):
         step, cache_kv = L.attention_decode(p, x[:, pos:pos + 1],
-                                            cache_kv, pos, cfg)
+                                            cache_kv, 0, pos, cfg)
         np.testing.assert_allclose(np.asarray(step[:, 0]),
                                    np.asarray(prefill[:, pos]),
                                    rtol=2e-3, atol=2e-3)

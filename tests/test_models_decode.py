@@ -1,7 +1,8 @@
 """Serving correctness: prefill + one-token decode steps must reproduce the
 teacher-forced forward logits for every cache type (GQA KV, MLA compressed
 KV with absorbed decode, Mamba SSM state + conv window, jamba's mix,
-whisper's self+cross caches)."""
+whisper's self+cross caches), and the donated step (the cache updated in
+place) must give the same logits and cache as the undonated one."""
 
 import jax
 import jax.numpy as jnp
@@ -17,23 +18,76 @@ CASES = ["smollm-135m", "deepseek-v3-671b", "mamba2-2.7b",
          "jamba-1.5-large-398b"]
 
 
+def _decode(model, params, cache, feed, donate):
+    """Run ``feed`` (a list of (tokens, pos)) through the jitted decode
+    step; returns the logits of each step and the final cache."""
+    step = jax.jit(model.decode_step, donate_argnums=(1,) if donate else ())
+    logits = []
+    for toks, pos in feed:
+        lg, cache = step(params, cache, toks, pos)
+        logits.append(np.asarray(lg[:, 0], np.float32))
+    return logits, cache
+
+
+def _same_as_undonated(model, params, cache, feed):
+    """The undonated run, then the donated one from the same cache
+    (which it consumes): bit-identical logits and caches."""
+    want, want_cache = _decode(model, params, cache, feed, donate=False)
+    got, got_cache = _decode(model, params, cache, feed, donate=True)
+    assert all(c.is_deleted() for c in jax.tree.leaves(cache))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(
+        np.asarray(g, np.float32), np.asarray(w, np.float32)),
+        got_cache, want_cache)
+    return got, got_cache
+
+
 @pytest.mark.parametrize("arch", CASES)
 def test_prefill_decode_matches_forward(arch, rng):
+    """Two sequences prefilled to different lengths decode in one batch,
+    each at its own position (ragged), until the longer writes row
+    ``max_len - 1``."""
     cfg = get_reduced_config(arch)
     model = build_model(cfg)
     params, _ = model.init_params(jax.random.key(1))
-    B, S, P = 2, 12, 8
+    B, S = 2, 12
+    starts = np.array([8, 5], np.int32)
+    steps = S - starts.max()
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, S)), jnp.int32)
     full = model.forward(params, toks)
-    lp, cache = model.prefill(params, toks[:, :P], max_len=S)
-    np.testing.assert_allclose(np.asarray(lp[:, -1], np.float32),
-                               np.asarray(full[:, P - 1], np.float32),
-                               atol=1e-4, rtol=1e-4)
-    for t in range(P, S):
-        lg, cache = model.decode_step(params, cache, toks[:, t:t + 1], t)
-        np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
-                                   np.asarray(full[:, t], np.float32),
+    parts = []
+    for i, p in enumerate(starts):
+        lp, c = model.prefill(params, toks[i:i + 1, :p], max_len=S)
+        np.testing.assert_allclose(np.asarray(lp[0, -1], np.float32),
+                                   np.asarray(full[i, p - 1], np.float32),
                                    atol=1e-4, rtol=1e-4)
+        parts.append(c)
+    # stacked cache leaves are (n_layers, batch, ...)
+    cache = jax.tree.map(lambda *a: jnp.concatenate(a, axis=1), *parts)
+    rows = np.arange(B)
+    feed = [(toks[rows, starts + t][:, None], jnp.asarray(starts + t))
+            for t in range(steps)]
+    logits, cache = _same_as_undonated(model, params, cache, feed)
+    for t, lg in enumerate(logits):
+        np.testing.assert_allclose(
+            lg, np.asarray(full[rows, starts + t], np.float32),
+            atol=1e-4, rtol=1e-4)
+    # every row a step wrote sits where the prefill of the whole
+    # sequence puts it (KV caches, by the position axis of one
+    # sequence's stacked leaf; an SSM state has no rows)
+    _, whole = model.prefill(params, toks, max_len=S)
+    leaves = jax.tree_util.tree_leaves_with_path
+    for (path, g), w in zip(leaves(cache), jax.tree.leaves(whole)):
+        ax = {"k": 2, "v": 2, "ckv": 1, "krope": 1}.get(path[-1].key)
+        if ax is None:
+            continue
+        for i, p in enumerate(starts):
+            n = np.arange(p + steps)
+            np.testing.assert_allclose(
+                np.asarray(jnp.take(g[:, i], n, ax), np.float32),
+                np.asarray(jnp.take(w[:, i], n, ax), np.float32),
+                atol=1e-4, rtol=1e-4)
 
 
 def test_whisper_prefill_decode(rng):
@@ -49,10 +103,12 @@ def test_whisper_prefill_decode(rng):
     np.testing.assert_allclose(np.asarray(lp[:, -1], np.float32),
                                np.asarray(full[:, P - 1], np.float32),
                                atol=1e-4, rtol=1e-4)
-    for t in range(P, S):
-        lg, cache = model.decode_step(params, cache, toks[:, t:t + 1], t)
-        np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
-                                   np.asarray(full[:, t], np.float32),
+    # the last step writes row max_len - 1
+    feed = [(toks[:, t:t + 1], jnp.asarray(t, jnp.int32))
+            for t in range(P, S)]
+    logits, _ = _same_as_undonated(model, params, cache, feed)
+    for t, lg in zip(range(P, S), logits):
+        np.testing.assert_allclose(lg, np.asarray(full[:, t], np.float32),
                                    atol=1e-4, rtol=1e-4)
 
 

@@ -121,6 +121,33 @@ def test_zero_recompiles_after_warmup_pallas(fresh_cache):
     assert report.n_completed == len(trace)
 
 
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_insert_updates_the_cache_in_place(fresh_cache, backend):
+    """Warm-up reads from the compiled insert and decode programs how much
+    of the cache each aliases.  Insert takes the cache as a donation and
+    writes its slot in place (all of it aliased; the buffers it was given
+    are gone after the call); decode writes a new cache and leaves the
+    one it was given valid."""
+    engine = Engine(_tiny_cfg(backend), max_batch=2, max_len=24,
+                    prompt_buckets=(8,), sampling="greedy", seed=0)
+    engine.warmup()
+    assert engine.cache_alias_share == {"decode": 0.0, "insert": 1.0}
+    before = jax.tree.leaves(engine.caches)
+    report = engine.run([Request(rid=0, prompt=(1, 2, 3), max_new_tokens=3,
+                                 arrival_step=0)])
+    assert report.cache_alias_share == {"decode": 0.0, "insert": 1.0}
+    assert all(a.is_deleted() for a in before)
+    live = engine.caches
+    b = engine.max_batch
+    _, after = engine._decode(engine.params, live,
+                              jnp.zeros((b, 1), jnp.int32),
+                              jnp.zeros((b,), jnp.int32))
+    assert not any(a.is_deleted() for a in jax.tree.leaves((live, after)))
+    assert report.n_completed == 1 and report.decode_recompiles == 0
+    d = report.to_json()
+    assert d["cache_alias_share"] == {"decode": 0.0, "insert": 1.0}
+
+
 def test_serve_config_run_api(fresh_cache):
     """ServeConfig + run(cfg) -> ServeReport, JSON-serializable."""
     import json
