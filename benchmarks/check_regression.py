@@ -38,9 +38,8 @@ trace is seeded, so any drift is a scheduler behaviour change — while
 **decode recompiles** and **Pallas fallbacks** must be zero on every
 current serve row, pinned or not (one persistent megakernel per shape
 bucket is the whole point of the serving tentpole), and so must the
-resilience and self-healing counters (``degradations``,
-``quarantined``, ``repromotions``, ``probes``, ``probe_failures`` —
-the clean path never demotes, never probes, never heals).  Throughput
+resilience counters (``degradations``, ``quarantined`` — the clean
+path never demotes and never quarantines).  Throughput
 (``tokens_per_s``) gets the same generous same-machine treatment as the
 speedup ratio: only a >1.5x collapse below the pin fails.
 
@@ -71,17 +70,13 @@ GATED_KEYS = ("pred_traffic_reduction", "pallas_regions",
               "region_spearman")
 # serving rows: exact pins for the deterministic scheduler counters,
 # ratio-gated throughput, and the zero-recompile / zero-fallback pins.
-# degradations/quarantined are the resilience counters, and
-# repromotions/probes/probe_failures the self-healing counters: all
-# pinned at zero on the clean path (neither the fault machinery nor the
-# health ledger may cost the happy path)
+# degradations/quarantined are the resilience counters, pinned at zero
+# on the clean path (the fault machinery may not cost the happy path)
 GATED_SERVE_KEYS = ("tokens_per_s", "completed", "rejected", "stalled",
                     "warmup_compiles", "decode_recompiles",
-                    "pallas_fallbacks", "degradations", "quarantined",
-                    "repromotions", "probes", "probe_failures")
+                    "pallas_fallbacks", "degradations", "quarantined")
 SERVE_EXACT_KEYS = ("completed", "rejected", "stalled", "warmup_compiles",
-                    "degradations", "quarantined", "repromotions",
-                    "probes", "probe_failures")
+                    "degradations", "quarantined")
 
 
 def _parse_derived(derived: str) -> dict:
@@ -264,8 +259,7 @@ def main(argv) -> int:
     # (or a region that falls off the megakernel path) always fails
     for name, cur in sorted(cur_srv.items()):
         for k in ("decode_recompiles", "pallas_fallbacks",
-                  "degradations", "quarantined", "repromotions",
-                  "probes", "probe_failures"):
+                  "degradations", "quarantined"):
             v = cur.get(k)
             if v is not None and v != "0":
                 failures.append(f"{name}: {k}={v} (must be 0)")

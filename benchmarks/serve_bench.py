@@ -18,25 +18,17 @@ Chaos mode (``--faults chaos``, the CI ``chaos`` job) runs the preset
 twice against a throwaway kernel-cache dir — once clean, once under a
 seeded ``resilience.FaultPlan`` injecting a Pallas compile failure at
 the grouped AND ungrouped rungs (so the ladder is exercised down to the
-jax rung), one corrupted on-disk plan, and one NaN decode step — and
-gates internally:
+jax rung) and one corrupted on-disk plan, with one NaN logits row
+planted in the third decode step by wrapping the engine's ``_decode`` —
+and gates internally:
 
 * every non-poisoned request completes, tokens byte-identical to the
   clean run;
 * ``degradations`` equals the number of compile faults in the plan,
   ``quarantined``/``corrupt_plans`` match the cache faults exactly, and
-  ``n_poisoned`` matches the NaN faults;
+  ``n_poisoned`` is exactly 1;
 * chaos tokens/sec stays within the same 1.5x collapse gate, measured
   against this runner's own clean pass.
-
-Heal mode (``--faults heal``, the CI ``chaos`` job's second step) drives
-the self-healing loop end-to-end: a transient decode fault fires once
-and stops, the watchdog demotes decode to the jax rung, and the health
-ledger's half-open probe must re-promote back to the grouped pallas
-rung mid-run — with the first probe itself faulted, so the breaker
-re-opens at doubled cool-down before the second probe heals.  Gates pin
-``repromotions`` / ``probes`` / ``probe_failures`` EXACTLY against the
-plan and require tokens byte-identical to the clean pass.
 """
 
 from __future__ import annotations
@@ -70,8 +62,8 @@ def _presets():
 
 
 # the seeded chaos plan: one compile failure at the grouped AND the
-# ungrouped rung (first compile of warmup -> ladder lands on jax), the
-# first on-disk plan read corrupted, one NaN decode step mid-run
+# ungrouped rung (first compile of warmup -> ladder lands on jax) and
+# the first on-disk plan read corrupted
 def _chaos_plan():
     from repro import resilience as RZ
     return RZ.FaultPlan([
@@ -80,22 +72,30 @@ def _chaos_plan():
         RZ.FaultSpec(site="compile:ungrouped", indices=(0,), kind="raise",
                      message="chaos: ungrouped lowering down"),
         RZ.FaultSpec(site="cache:get_plan", indices=(0,), kind="corrupt"),
-        RZ.FaultSpec(site="serve:logits", indices=(2,), kind="nan"),
     ], seed=0)
 
 
-# the seeded heal plan: one transient decode fault (fires once, then
-# the rung is healthy again) plus one faulted re-promotion probe, so
-# the breaker re-opens at doubled cool-down before the second probe
-# swaps the pallas rung back in
-def _heal_plan():
-    from repro import resilience as RZ
-    return RZ.FaultPlan([
-        RZ.FaultSpec(site="serve:decode", indices=(2,), kind="raise",
-                     message="heal: transient decode fault"),
-        RZ.FaultSpec(site="serve:probe", indices=(0,), kind="raise",
-                     message="heal: probe still cold"),
-    ], seed=0)
+# the 0-based decode call after warm-up whose logits get one NaN row
+POISON_DECODE_INDEX = 2
+
+
+def _poison_decode(engine, index: int = POISON_DECODE_INDEX) -> None:
+    """Wrap ``engine._decode`` so its ``index``-th call returns a NaN
+    logits row for the first active slot; the engine's finite-logits
+    guard must contain it to that one sequence."""
+    import jax.numpy as jnp
+
+    decode, calls = engine._decode, []
+
+    def poisoned(params, caches, tokens, pos):
+        logits, caches = decode(params, caches, tokens, pos)
+        calls.append(1)
+        if len(calls) - 1 == index:
+            row = next(i for i, s in enumerate(engine.slots) if s)
+            logits = logits.at[row, -1].set(jnp.nan)
+        return logits, caches
+
+    engine._decode = poisoned
 
 
 def _row(preset: str, cfg, report) -> dict:
@@ -121,9 +121,6 @@ def _row(preset: str, cfg, report) -> dict:
         f"degradations={report.degradations}",
         f"quarantined={report.quarantined}",
         f"poisoned={report.n_poisoned}",
-        f"repromotions={report.repromotions}",
-        f"probes={report.probes}",
-        f"probe_failures={report.probe_failures}",
         f"cache_hit_rate={report.cache_hit_rate:.3f}",
     ])
     return {"name": f"serve_{cfg.arch}_{preset}",
@@ -142,7 +139,7 @@ def chaos(preset: str) -> dict:
     seeded fault plan, gated against the clean pass.  Returns
     ``{"row", "report", "failures": [...]}`` — empty failures = pass."""
     from repro import pipeline, resilience as RZ
-    from repro.launch.serve import run
+    from repro.launch.serve import build_engine, run
 
     cfg = _presets()[preset]
     cache_dir = tempfile.mkdtemp(prefix="repro-chaos-cache-")
@@ -160,7 +157,10 @@ def chaos(preset: str) -> dict:
     pipeline.reset_default_cache()
     plan = _chaos_plan()
     with RZ.faults(plan):
-        faulted = run(cfg)
+        engine = build_engine(cfg)
+        engine.warmup()
+        _poison_decode(engine)
+        faulted = run(cfg, engine)
     stats = pipeline.default_cache().stats
 
     failures = []
@@ -172,7 +172,7 @@ def chaos(preset: str) -> dict:
     poisoned = {f["rid"] for f in faulted.failures
                 if f["reason"] in ("nonfinite_logits",
                                    "nonfinite_prefill")}
-    n_nan = plan.expected_count("serve:logits")
+    n_nan = 1  # the row _poison_decode plants
     n_compile = plan.expected_count("compile:")
     n_cache = plan.expected_count("cache:")
 
@@ -219,97 +219,11 @@ def chaos(preset: str) -> dict:
             "failures": failures, "plan": plan.to_json()}
 
 
-def heal(preset: str) -> dict:
-    """The self-healing harness: clean pass, then the same preset under
-    a transient decode fault plus a faulted first probe, with a short
-    re-promotion window.  Gates pin the full breaker lifecycle —
-    demote -> failed probe (doubled cool-down) -> successful probe ->
-    re-promotion to the grouped pallas rung — EXACTLY against the plan."""
-    import dataclasses
-
-    from repro import pipeline, resilience as RZ
-    from repro.launch.serve import run
-
-    # a short probe window so the lifecycle completes inside the preset
-    # trace: demote ~tick 2, failed probe 3 ticks later, breaker doubles
-    # to 6, healing probe ~tick 12
-    cfg = dataclasses.replace(_presets()[preset], repromote_after=3)
-    cache_dir = tempfile.mkdtemp(prefix="repro-heal-cache-")
-    os.environ["REPRO_KERNEL_CACHE"] = cache_dir
-    pipeline.reset_default_cache()
-
-    clean = run(cfg)
-    pipeline.reset_default_cache()
-    plan = _heal_plan()
-    with RZ.faults(plan):
-        faulted = run(cfg)
-
-    failures = []
-
-    def gate(ok: bool, what: str):
-        if not ok:
-            failures.append(what)
-
-    n_decode = plan.expected_count("serve:decode")
-    n_probe_faults = plan.expected_count("serve:probe")
-
-    gate(plan.fired_count() == len(plan.specs),
-         f"every planned fault fires (fired {plan.fired_count()}/"
-         f"{len(plan.specs)}: {plan.fired})")
-    gate(faulted.degradations == n_decode,
-         f"watchdog demotions match the plan "
-         f"({faulted.degradations} != {n_decode})")
-    gate(faulted.repromotions == n_decode,
-         f"every demotion healed: re-promotions match the plan "
-         f"({faulted.repromotions} != {n_decode})")
-    gate(faulted.probe_failures == n_probe_faults,
-         f"probe failures match the plan "
-         f"({faulted.probe_failures} != {n_probe_faults})")
-    gate(faulted.probes == n_decode + n_probe_faults,
-         f"probe count matches the plan: one per planned probe fault "
-         f"plus one healing probe ({faulted.probes} != "
-         f"{n_decode + n_probe_faults})")
-    gate(faulted.decode_backend == "pipeline-pallas",
-         f"decode ended the run back on the grouped pallas rung "
-         f"(ended on {faulted.decode_backend!r})")
-    gate(faulted.n_completed == clean.n_completed,
-         f"a transient fault poisons nothing: all requests complete "
-         f"({faulted.n_completed} != {clean.n_completed})")
-    mismatched = [r for r in clean.tokens
-                  if clean.tokens[r] != faulted.tokens.get(r)]
-    gate(not mismatched,
-         f"tokens byte-identical to the clean run across demote AND "
-         f"re-promote (mismatched rids {mismatched})")
-    gate(faulted.decode_recompiles == 0,
-         f"demotion/probe compiles stay off the strict-no-recompile "
-         f"books ({faulted.decode_recompiles} != 0)")
-    gate(faulted.quarantined == 0 and faulted.n_poisoned == 0,
-         f"no cache or numeric casualties (quarantined="
-         f"{faulted.quarantined} poisoned={faulted.n_poisoned})")
-    # no 1.5x throughput gate here: the heal pass pays two mid-run jit
-    # rebuilds (the demotion build and the probe re-compile) inside a
-    # deliberately tiny CI trace, so wall time is compile-dominated by
-    # design.  A 20x collapse guard still catches hangs and pathological
-    # probe loops
-    gate(faulted.tokens_per_s >= clean.tokens_per_s / 20.0,
-         f"heal tokens/sec within the 20x hang guard "
-         f"({faulted.tokens_per_s:.1f} vs clean {clean.tokens_per_s:.1f})")
-    gate(clean.repromotions == 0 and clean.probes == 0
-         and clean.probe_failures == 0 and clean.degradations == 0,
-         f"clean pass has zero self-healing counters (repromotions="
-         f"{clean.repromotions} probes={clean.probes} probe_failures="
-         f"{clean.probe_failures} degradations={clean.degradations})")
-
-    row = _row(f"{preset}_heal", cfg, faulted)
-    return {"row": row, "report": faulted, "clean": clean,
-            "failures": failures, "plan": plan.to_json()}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="ci", choices=sorted(PRESET_ARGS))
-    ap.add_argument("--faults", default=None, choices=("chaos", "heal"),
-                    help="run a seeded fault harness instead of the "
+    ap.add_argument("--faults", default=None, choices=("chaos",),
+                    help="run the seeded fault harness instead of the "
                          "clean bench (gates internally, exit 1 on any "
                          "gate failure)")
     ap.add_argument("--json", dest="json_out", default=None,
@@ -320,16 +234,16 @@ def main(argv=None) -> int:
     from repro.runtime import compile_cache
     compile_cache.configure()
 
-    if args.faults in ("chaos", "heal"):
-        out = (chaos if args.faults == "chaos" else heal)(args.preset)
+    if args.faults == "chaos":
+        out = chaos(args.preset)
         row, report = out["row"], out["report"]
         print(f"{row['name']},{row['us_per_call']:.1f},{row['derived']}")
         for f in out["failures"]:
             print(f"{args.faults.upper()} GATE FAILED: {f}")
         if not out["failures"]:
             print(f"{args.faults} gates passed: "
-                  f"{len(out['plan']['faults'])} faults injected, every "
-                  "counter matched the plan")
+                  f"{len(out['plan']['faults'])} planned faults and one "
+                  "NaN decode row injected, every counter matched")
         if args.report:
             with open(args.report, "w") as fh:
                 json.dump({args.faults: report.to_json(),

@@ -24,8 +24,8 @@ ladder is capped at the grouped rung, so a kernel the TPU compiler
 refuses raises with the compiler's own message.
 
 The kernel plan cache starts empty (a fresh temporary directory), so no
-plan, calibration profile or health-ledger state of an earlier run can
-steer this one.  On success the last line of standard output is
+plan or calibration profile of an earlier run can steer this one.  On
+success the last line of standard output is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
 
@@ -56,7 +56,7 @@ SERVE = dict(arch="smollm-135m", reduced=False, backend="pallas",
              dtype="float32", max_batch=8, max_len=1024,
              prompt_buckets=(64, 128, 256), n_requests=16,
              prompt_lens=(32, 256), gen_lens=(16, 64), seed=0,
-             max_rung="grouped", repromote_after=None)
+             max_rung="grouped")
 
 
 class SmokeFailure(RuntimeError):

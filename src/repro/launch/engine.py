@@ -41,7 +41,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 from jax.profiler import TraceAnnotation as span
 
-from repro import resilience as RZ
+from repro.resilience import METRICS
 
 # families whose padded-bucket prefill is exactly correct: causal
 # attention masks the pad positions; an SSM scan would carry pad state
@@ -181,14 +181,8 @@ class ServeReport:
                                  # tick-watchdog decode demotions
     quarantined: int = 0         # corrupt cache entries quarantined
                                  # (CacheStats delta over the run)
-    # -- self-healing counters (the inverse of the watchdog) ------------
-    repromotions: int = 0        # demoted decode rungs probed healthy and
-                                 # swapped back in mid-run
-    probes: int = 0              # half-open re-promotion probes attempted
-    probe_failures: int = 0      # probes that failed (breaker re-opened
-                                 # at doubled cool-down)
     decode_backend: str = ""     # the rung decode ended the run on
-                                 # (e.g. "pipeline-pallas" when healed)
+                                 # ("pipeline-jax" after one demotion)
     # bytes each compiled program aliases from the KV cache it is given
     # over the cache's bytes, read at warm-up: 1.0 when the program
     # updates the cache in place, 0.0 when it writes a new one
@@ -265,6 +259,11 @@ class Engine:
     one token through a single jitted ragged decode step (positions as a
     ``(B,)`` vector).  All pipeline kernels are compiled in ``warmup()``;
     after that the run loop never compiles again — ``run()`` asserts it.
+
+    A decode step that raises is rebuilt one rung down (``_demote_cfg``)
+    and stays there for the engine's lifetime.  ``repromote_after``
+    accepts only ``None``; it remains so that callers written when a
+    demoted rung could be re-promoted still construct an engine.
     """
 
     def __init__(self, cfg, *, max_batch: int = 4, max_len: int = 96,
@@ -273,12 +272,14 @@ class Engine:
                  seed: int = 0, keep_per_step: bool = True,
                  strict_no_recompile: bool = True,
                  max_queue: Optional[int] = None,
-                 repromote_after: Optional[int] = 8):
+                 repromote_after: None = None):
         import jax
 
-        from repro import pipeline
         from repro.models import build_model
 
+        if repromote_after is not None:
+            raise ValueError("repromote_after accepts only None: a demoted "
+                             "decode rung stays demoted")
         if cfg.family not in _SUPPORTED_FAMILIES:
             raise ValueError(
                 f"continuous batching supports attention-family archs "
@@ -303,57 +304,6 @@ class Engine:
         # unbounded backlog.  None = unbounded (the historical behavior)
         self.max_queue = None if max_queue is None else int(max_queue)
         self._key = jax.random.key(seed)
-
-        # -- self-healing (the inverse of the tick watchdog) ----------------
-        # after `repromote_after` clean decode ticks on a demoted rung, a
-        # half-open probe re-compiles the original rung off the hot path
-        # and swaps it back if it passes the finite-logits guard.  None
-        # disables re-promotion (the PR-9 demote-forever behavior).  The
-        # ledger's clock is the engine tick counter, so breaker timing is
-        # deterministic per trace; state persists under <cache>/health/.
-        self.repromote_after = (None if repromote_after is None
-                                else int(repromote_after))
-        self._tick = 0
-        self._clean_ticks = 0
-        self._demote_stack: List[Tuple[object, str]] = []  # (cfg, rung)
-        self.repromotions = 0
-        self.probes = 0
-        self.probe_failures = 0
-        self.probe_compiles = 0      # compiles explained by probes
-        self._ledger = None
-        self._hkey = f"serve:{getattr(cfg, 'name', 'model')}:decode"
-        if self.repromote_after is not None:
-            if self.repromote_after <= 0:
-                raise ValueError("repromote_after must be > 0 (or None "
-                                 "to disable re-promotion)")
-            cache = pipeline.default_cache()
-            self._ledger = RZ.HealthLedger(
-                cache.root / "health" if cache.disk else None,
-                clock=lambda: float(self._tick))
-            self._breaker_policy = RZ.ResiliencePolicy(
-                breaker_threshold=1,  # one decode crash opens the breaker
-                breaker_cooldown_s=float(self.repromote_after),
-                breaker_cooldown_max_s=float(self.repromote_after) * 64)
-            # adopt persisted breaker state from a crashed/restarted
-            # predecessor: start demoted rather than re-crash the same
-            # rung, and re-open the cool-down against OUR tick clock
-            while True:
-                lbl = _backend_label(cfg)
-                if self._ledger.state(self._hkey, lbl) == "closed":
-                    break
-                new_cfg, _ = _demote_cfg(cfg)
-                if new_cfg is None:
-                    break
-                self._demote_stack.append((cfg, lbl))
-                self._ledger.reopen(self._hkey, lbl,
-                                    float(self.repromote_after))
-                warnings.warn(
-                    f"serve: decode rung {lbl!r} breaker is open in the "
-                    f"health ledger; starting demoted to "
-                    f"{_backend_label(new_cfg)!r}", RuntimeWarning,
-                    stacklevel=2)
-                cfg = new_cfg
-            self.cfg = cfg
 
         self.model = build_model(cfg)
         self.params, _ = self.model.init_params(jax.random.key(seed))
@@ -431,7 +381,7 @@ class Engine:
         stats = pipeline.default_cache().stats
         before = stats.snapshot()
         self._base_stats = before
-        self._base_metrics = RZ.METRICS.snapshot()
+        self._base_metrics = METRICS.snapshot()
         with warnings.catch_warnings(record=True) as caught:
             warnings.simplefilter("always")
             for b in self.prompt_buckets:
@@ -537,7 +487,6 @@ class Engine:
     def _decode_once(self):
         jnp = self._jax.numpy
         with span("engine.decode"):
-            RZ.check("serve:decode")
             return self._decode(
                 self.params, self.caches,
                 jnp.asarray(self._token_vector()[:, None]),
@@ -556,18 +505,9 @@ class Engine:
         new_cfg, label = _demote_cfg(self.cfg)
         if new_cfg is None:
             raise err
-        jax = self._jax
-        if self._ledger is not None:
-            # open the failed rung's breaker (threshold 1: a decode crash
-            # is never cheap) so re-promotion waits out the cool-down
-            old_label = _backend_label(self.cfg)
-            self._demote_stack.append((self.cfg, old_label))
-            self._ledger.record_failure(self._hkey, old_label, err,
-                                        policy=self._breaker_policy)
         self.cfg = new_cfg
         self.model = build_model(new_cfg)
-        m = self.model
-        self._decode = jax.jit(m.decode_step)
+        self._decode = self._jax.jit(self.model.decode_step)
         self.watchdog_demotions += 1
         report.failures.append({
             "reason": "decode_demotion", "step": step, "to": label,
@@ -576,68 +516,6 @@ class Engine:
             f"serve watchdog: decode step failed "
             f"({type(err).__name__}: {err}); demoted decode to {label} "
             "and continuing", RuntimeWarning, stacklevel=2)
-
-    def _probe_repromote(self, report: ServeReport, step: int,
-                         stats) -> bool:
-        """Half-open probe of the rung decode was demoted off: rebuild
-        it and run one decode step against the live KV cache *without*
-        committing its outputs (the real tick already ran).  A
-        finite-logits pass swaps the healthy rung back in and closes the
-        breaker; a failure re-opens it at doubled cool-down.  Probe
-        compiles are explained (excluded from ``strict_no_recompile``)
-        the same way demotion compiles are."""
-        from repro.models import build_model
-
-        jnp = self._jax.numpy
-        old_cfg, old_label = self._demote_stack[-1]
-        self.probes += 1
-        before = stats.snapshot()
-        try:
-            spec = RZ.fire("serve:probe")
-            if spec is not None and spec.kind == "raise":
-                raise RZ.InjectedFault(f"serve:probe[{spec.message}]")
-            model = build_model(old_cfg)
-            decode = self._jax.jit(model.decode_step)
-            logits, _ = decode(  # outputs discarded: probe only
-                self.params, self.caches,
-                jnp.asarray(self._token_vector()[:, None]),
-                jnp.asarray(self._pos_vector()))
-            if spec is not None and spec.kind == "nan":
-                logits = logits.at[:, -1].set(jnp.nan)
-            with span("engine.sync.probe_guard"):
-                finite = bool(jnp.all(jnp.isfinite(logits[:, -1])))
-            if not finite:
-                raise RuntimeError("probe produced non-finite logits")
-        except Exception as e:
-            self.probe_failures += 1
-            self._ledger.record_failure(self._hkey, old_label, e,
-                                        policy=self._breaker_policy)
-            report.failures.append({
-                "reason": "probe_failed", "step": step, "rung": old_label,
-                "error": f"{type(e).__name__}: {e}"})
-            warnings.warn(
-                f"serve: re-promotion probe of {old_label!r} failed "
-                f"({type(e).__name__}: {e}); breaker re-opened at doubled "
-                "cool-down", RuntimeWarning, stacklevel=2)
-            self._clean_ticks = 0
-            self.probe_compiles += stats.delta(before).compiles
-            self._warm_stats = stats.snapshot()
-            return False
-        # healthy again: swap the probed decode in and close the breaker
-        self.cfg, self.model, self._decode = old_cfg, model, decode
-        self._demote_stack.pop()
-        self.repromotions += 1
-        self._ledger.record_success(self._hkey, old_label)
-        report.failures.append({
-            "reason": "decode_repromotion", "step": step, "to": old_label})
-        warnings.warn(
-            f"serve: decode rung {old_label!r} probed healthy after "
-            f"{self._clean_ticks} clean ticks; re-promoted",
-            RuntimeWarning, stacklevel=2)
-        self._clean_ticks = 0
-        self.probe_compiles += stats.delta(before).compiles
-        self._warm_stats = stats.snapshot()
-        return True
 
     def run(self, trace: Sequence[Request],
             max_steps: Optional[int] = None) -> ServeReport:
@@ -661,7 +539,6 @@ class Engine:
         while pending or self.queue or any(self.slots):
             if max_steps is not None and step >= max_steps:
                 break
-            self._tick = step  # the health ledger's deterministic clock
             t0 = time.perf_counter()
             with span("engine.tick", step=step, n_active=sum(
                     s is not None for s in self.slots)):
@@ -701,12 +578,9 @@ class Engine:
         # resilience counters over the whole engine lifetime (warmup
         # included): compile-ladder demotions + watchdog demotions, and
         # cache-integrity quarantines
-        report.degradations = (RZ.METRICS.delta(self._base_metrics)
+        report.degradations = (METRICS.delta(self._base_metrics)
                                .demotions + self.watchdog_demotions)
         report.quarantined = stats.delta(self._base_stats).quarantined
-        report.repromotions = self.repromotions
-        report.probes = self.probes
-        report.probe_failures = self.probe_failures
         report.decode_backend = _backend_label(self.cfg)
         report.cache_alias_share = dict(self.cache_alias_share)
         if self.strict_no_recompile and report.decode_recompiles:
@@ -757,7 +631,6 @@ class Engine:
         if active:
             try:
                 logits, caches = self._decode_once()
-                self._clean_ticks += 1
             except Exception as e:  # watchdog: demote, retry once
                 before = stats.snapshot()
                 self._watchdog_demote(e, step, report)
@@ -766,13 +639,7 @@ class Engine:
                 # strict_no_recompile armed for *unexplained* ones
                 self.demotion_compiles += stats.delta(before).compiles
                 self._warm_stats = stats.snapshot()
-                self._clean_ticks = 0
             self.caches = caches
-            spec = RZ.fire("serve:logits")
-            if spec is not None and spec.kind == "nan":
-                # poison exactly one co-batched row; the guard below
-                # must contain it to that sequence
-                logits = logits.at[active[0], -1].set(jnp.nan)
             # cheap post-step guard: one finite-check over the new
             # logits row per slot, evict poisoned sequences instead of
             # letting NaNs propagate through their KV cache
@@ -785,14 +652,6 @@ class Engine:
             with span("engine.retire"):
                 n_decode = self._retire(active, fin, sampled, now, step,
                                         report)
-        # re-promotion: after enough clean ticks on a demoted rung, let
-        # the breaker admit one half-open probe of the original
-        if (self._demote_stack and self._ledger is not None
-                and self._clean_ticks >= self.repromote_after
-                and self._ledger.decision(
-                    self._hkey, self._demote_stack[-1][1]) == "probe"):
-            with span("engine.repromote"):
-                self._probe_repromote(report, step, stats)
         return n_prefill, n_decode
 
     def _retire(self, active: List[int], fin: np.ndarray,

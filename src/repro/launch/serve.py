@@ -59,10 +59,6 @@ class ServeConfig:
     max_steps: Optional[int] = None
     keep_per_step: bool = True
     strict_no_recompile: bool = True
-    # -- self-healing -------------------------------------------------------
-    # clean decode ticks on a demoted rung before a half-open probe may
-    # re-promote the original; None disables re-promotion
-    repromote_after: Optional[int] = 8
 
 
 def build_engine(cfg: ServeConfig) -> Engine:
@@ -84,8 +80,7 @@ def build_engine(cfg: ServeConfig) -> Engine:
                   sampling=cfg.sampling, temperature=cfg.temperature,
                   seed=cfg.seed, keep_per_step=cfg.keep_per_step,
                   strict_no_recompile=cfg.strict_no_recompile,
-                  max_queue=cfg.max_queue,
-                  repromote_after=cfg.repromote_after)
+                  max_queue=cfg.max_queue)
 
 
 def trace_of(cfg: ServeConfig, vocab: int):
@@ -127,10 +122,6 @@ def main(argv=None) -> ServeReport:
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-steps", type=int, default=None)
-    ap.add_argument("--repromote-after", type=int, default=8,
-                    help="clean decode ticks on a demoted rung before a "
-                         "half-open probe may re-promote the original "
-                         "(0 disables re-promotion)")
     ap.add_argument("--json", dest="json_out", default=None,
                     help="write the full ServeReport as JSON")
     args = ap.parse_args(argv)
@@ -147,10 +138,7 @@ def main(argv=None) -> ServeReport:
                       gen_lens=tuple(args.gen_lens),
                       sampling=args.sampling,
                       temperature=args.temperature, seed=args.seed,
-                      max_steps=args.max_steps,
-                      repromote_after=(args.repromote_after
-                                       if args.repromote_after > 0
-                                       else None))
+                      max_steps=args.max_steps)
     report = run(cfg)
     print(f"arch={args.arch} backend={args.backend} "
           f"requests={report.n_completed}/{report.n_requests} "
@@ -160,8 +148,7 @@ def main(argv=None) -> ServeReport:
           f"itl p50/p99={report.itl_p50_ms:.2f}/{report.itl_p99_ms:.2f}ms "
           f"occupancy={report.mean_occupancy:.2f} "
           f"cache_hit_rate={report.cache_hit_rate:.3f} "
-          f"recompiles={report.decode_recompiles} "
-          f"repromotions={report.repromotions}")
+          f"recompiles={report.decode_recompiles}")
     if args.json_out:
         with open(args.json_out, "w") as f:
             json.dump(report.to_json(), f, indent=1)

@@ -307,20 +307,7 @@ class KernelCache:
         self.max_disk_bytes = max_disk_bytes
         self._kernels: Dict[CacheKey, Any] = {}
         self.stats = CacheStats()
-        self._health = None
         self.recover()
-
-    @property
-    def health(self):
-        """This cache's :class:`resilience.HealthLedger` — breaker state
-        for compile rungs, persisted under ``<root>/health/`` (memory-
-        only for ``disk=False`` caches).  Built lazily and performs zero
-        I/O until a rung actually fails."""
-        if self._health is None:
-            from repro import resilience as RZ
-            self._health = RZ.HealthLedger(
-                self.root / "health" if self.disk else None)
-        return self._health
 
     # -- startup crash recovery --------------------------------------------
     def recover(self) -> None:
@@ -344,34 +331,33 @@ class KernelCache:
         except OSError:
             return
         now = time.time()
-        for d in (self.root, self.root / "health"):
-            try:
-                tmps = sorted(d.glob("*.tmp"))
-            except OSError:
-                continue
-            for tmp in tmps:
-                pid = _tmp_writer_pid(tmp.name)
-                if pid is not None and pid != os.getpid() \
-                        and not _pid_alive(pid):
-                    orphan = True
-                else:
-                    # our own pid, a live writer, or an unparseable name:
-                    # only reclaim once clearly abandoned by age
-                    try:
-                        orphan = now - tmp.stat().st_mtime > STALE_TMP_AGE_S
-                    except OSError:
-                        continue
-                if not orphan:
-                    continue
+        try:
+            tmps = sorted(self.root.glob("*.tmp"))
+        except OSError:
+            tmps = []
+        for tmp in tmps:
+            pid = _tmp_writer_pid(tmp.name)
+            if pid is not None and pid != os.getpid() \
+                    and not _pid_alive(pid):
+                orphan = True
+            else:
+                # our own pid, a live writer, or an unparseable name:
+                # only reclaim once clearly abandoned by age
                 try:
-                    tmp.unlink()
+                    orphan = now - tmp.stat().st_mtime > STALE_TMP_AGE_S
                 except OSError:
                     continue
-                self.stats.recovered_tmp += 1
-                warnings.warn(
-                    f"kernel cache: recovered orphaned tmp file {tmp} "
-                    f"(writer pid {pid} is gone)", RuntimeWarning,
-                    stacklevel=2)
+            if not orphan:
+                continue
+            try:
+                tmp.unlink()
+            except OSError:
+                continue
+            self.stats.recovered_tmp += 1
+            warnings.warn(
+                f"kernel cache: recovered orphaned tmp file {tmp} "
+                f"(writer pid {pid} is gone)", RuntimeWarning,
+                stacklevel=2)
         self._sweep_stale_lock(now)
         self._cap_quarantine()
 

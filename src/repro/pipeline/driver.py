@@ -284,88 +284,35 @@ def _rung_thunk(rung: str, g: Graph, dims: Dict[str, int], *,
 
 
 def _ladder_lower(rungs: Tuple[str, ...], make_thunk: Callable,
-                  policy, rr, *, ledger=None,
-                  health_key: Optional[str] = None) -> Tuple:
-    """Attempt each allowed rung in order — ``policy.retries`` extra
-    same-rung tries with exponential backoff, each attempt optionally
-    under ``policy.attempt_timeout_s`` — recording every attempt in the
-    :class:`resilience.ResilienceReport` ``rr``.  Returns the first
-    successful rung's ``(call, report)``; raises
-    :class:`resilience.LadderError` when every rung is exhausted.
-
-    When a :class:`resilience.HealthLedger` is given, each rung's
-    breaker is consulted first: an **open** breaker skips the rung
-    instantly (a zero-cost ``skipped_open`` attempt — no retry sleeps,
-    no timeout worker, no re-burning the budget a known-bad rung
-    already wasted), a cool-down-elapsed breaker admits the attempt as
-    a half-open **probe**, and every executed attempt's outcome feeds
-    back into the ledger.
-
-    The default policy costs the happy path nothing: no timeout means no
-    worker thread, zero retries means no sleep, and the ledger holds no
-    entry for a rung that never failed — one ``try`` around the
-    lowering call that already existed."""
+                  rr) -> Tuple:
+    """Attempt each allowed rung once, in order, recording every attempt
+    in the :class:`resilience.ResilienceReport` ``rr``.  Returns the
+    first successful rung's ``(call, report)``; a failure warns and
+    demotes to the next rung; raises :class:`resilience.LadderError`
+    when every rung is exhausted.  The happy path costs one ``try``
+    around the lowering call that already existed."""
     last: Optional[BaseException] = None
     for ri, rung in enumerate(rungs):
-        probe = False
-        if ledger is not None and health_key is not None:
-            verdict = ledger.decision(health_key, rung)
-            if verdict == "open":
-                rr.attempts.append(RZ.Attempt(
-                    rung, False, 0.0, error="breaker open (skipped)",
-                    skipped_open=True))
-                RZ.METRICS.skipped_open += 1
-                if ri + 1 < len(rungs):
-                    warnings.warn(
-                        f"compile ladder: rung {rung!r} breaker open; "
-                        f"skipping to {rungs[ri + 1]!r}", RuntimeWarning,
-                        stacklevel=3)
-                continue
-            probe = verdict == "probe"
-            if probe:
-                RZ.METRICS.probes += 1
         thunk = make_thunk(rung)
-
-        def attempt(rung=rung, thunk=thunk):
+        t0 = time.perf_counter()
+        try:
             RZ.check(f"compile:{rung}")
-            return thunk()
-
-        for retry in range(policy.retries + 1):
-            if retry:
-                time.sleep(policy.backoff_s * (2 ** (retry - 1)))
-            t0 = time.perf_counter()
-            try:
-                res = (RZ.run_with_timeout(attempt,
-                                           policy.attempt_timeout_s)
-                       if policy.attempt_timeout_s is not None
-                       else attempt())
-            except Exception as e:  # any lowering failure demotes
-                last = e
-                rr.attempts.append(RZ.Attempt(
-                    rung, False, time.perf_counter() - t0,
-                    error=f"{type(e).__name__}: {e}", retry=retry,
-                    timed_out=isinstance(e, RZ.AttemptTimeout),
-                    probe=probe))
-                if ledger is not None and health_key is not None:
-                    ledger.record_failure(health_key, rung, e,
-                                          policy=policy)
-                    if probe:
-                        RZ.METRICS.probe_failures += 1
-                        probe = False  # retries are ordinary attempts
-                continue
+            res = thunk()
+        except Exception as e:  # any lowering failure demotes
+            last = e
             rr.attempts.append(RZ.Attempt(
-                rung, True, time.perf_counter() - t0, retry=retry,
-                probe=probe))
-            rr.rung = rung
-            if ledger is not None and health_key is not None:
-                ledger.record_success(health_key, rung)
-            return res
-        if ri + 1 < len(rungs):
-            RZ.METRICS.demotions += 1
-            warnings.warn(
-                f"compile ladder: rung {rung!r} failed "
-                f"({rr.attempts[-1].error}); demoting to "
-                f"{rungs[ri + 1]!r}", RuntimeWarning, stacklevel=3)
+                rung, False, time.perf_counter() - t0,
+                error=f"{type(e).__name__}: {e}"))
+            if ri + 1 < len(rungs):
+                RZ.METRICS.demotions += 1
+                warnings.warn(
+                    f"compile ladder: rung {rung!r} failed "
+                    f"({rr.attempts[-1].error}); demoting to "
+                    f"{rungs[ri + 1]!r}", RuntimeWarning, stacklevel=3)
+            continue
+        rr.attempts.append(RZ.Attempt(rung, True, time.perf_counter() - t0))
+        rr.rung = rung
+        return res
     RZ.METRICS.ladder_failures += 1
     raise RZ.LadderError(
         f"every allowed ladder rung failed ({rr.summary()}); "
@@ -685,13 +632,7 @@ def compile(graph: Graph, dims: Optional[Dict[str, int]] = None, *,
                               dims=use_dims, blocks=blocks,
                               interpret=interpret, jit=jit, pplan=pplan,
                               gplan=gplan, group=group, name=name),
-            policy, rr,
-            # the cache's health ledger shares breaker state with every
-            # process pointed at the same cache dir; keyed by graph
-            # fingerprint so one program's bad rung never taints another
-            ledger=(cache.health if policy.breaker_threshold > 0
-                    else None),
-            health_key=graph.fingerprint())
+            rr)
     # thread the partitioner's RegionError (or emit_program's own
     # whole-program fallback, on the disk-hit path where the driver
     # never partitioned) through both provenance records

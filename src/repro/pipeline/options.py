@@ -74,12 +74,8 @@ class CompileOptions:
     profile: Optional[Any] = None
     # degradation-ladder policy (resilience.ResiliencePolicy): how far a
     # failing compile may demote (grouped -> ungrouped -> jax ->
-    # interpreter), per-attempt timeout, retry budget, and the health-
-    # ledger breaker knobs (breaker_threshold / breaker_cooldown_s /
-    # breaker_cooldown_max_s governing when a repeatedly-failing rung is
-    # skipped outright and when it is probed again).  None = the default
-    # policy (full ladder, no timeout, no retries, threshold-3 breaker),
-    # which keeps cache keys byte-identical to pre-resilience builds
+    # interpreter).  None = the default policy (the full ladder), which
+    # keeps cache keys byte-identical to pre-resilience builds
     resilience: Optional[Any] = None
 
     def __post_init__(self):
@@ -170,8 +166,8 @@ class CompileOptions:
         from repro import resilience as RZ
         policy = self._policy()
         if policy != RZ.DEFAULT_POLICY:
-            # a bounded ladder (max_rung above interpreter) or a timeout
-            # can change which rung's kernel gets cached in-process;
+            # a bounded ladder (max_rung above interpreter) can change
+            # which rung's kernel gets cached in-process;
             # keyed only when non-default so existing keys stay
             # byte-identical
             opts += (("resilience", policy.key()),)

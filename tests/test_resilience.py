@@ -3,8 +3,10 @@ faults (matrix over every in-repo program, outputs pinned to the
 interpreter oracle), kernel-cache integrity (checksums, quarantine,
 named counters), serving-engine fault isolation (poison eviction with
 co-batched oracle match, watchdog demotion, bounded admission,
-deadlines), and the deterministic FaultPlan machinery itself."""
+deadlines), the deterministic FaultPlan machinery itself, and that no
+failure leaves state behind for the next process."""
 
+import dataclasses
 import json
 import pickle
 
@@ -22,7 +24,7 @@ from test_lowering_coverage import PROGRAMS, _merged_inputs
 def fresh_cache(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_CACHE", str(tmp_path))
     pipeline.reset_default_cache()
-    yield
+    yield tmp_path
     pipeline.reset_default_cache()
 
 
@@ -57,9 +59,14 @@ def test_ladder_order_and_rungs_from():
 
 
 def test_policy_is_frozen_hashable_and_keyed():
-    p = RZ.ResiliencePolicy(max_rung="jax", retries=2)
+    assert [f.name for f in dataclasses.fields(RZ.ResiliencePolicy)] == [
+        "max_rung"]
+    p = RZ.ResiliencePolicy(max_rung="jax")
     assert hash(p) != hash(RZ.DEFAULT_POLICY)
-    assert p.key() == ("jax", None, 2, 0.05, 3, 60.0, 3600.0)
+    assert p.key() == ("jax",)
+    assert p == RZ.ResiliencePolicy(max_rung="jax")
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        p.max_rung = "grouped"
     with pytest.raises(ValueError):
         RZ.ResiliencePolicy(max_rung="nope")
     # non-default policies land in the cache-key opts; the default stays
@@ -136,54 +143,33 @@ def test_happy_path_report_is_one_ok_attempt(fresh_cache):
     assert js["demotions"] == 0 and js["rung"] == "grouped"
 
 
-def test_retry_recovers_at_same_rung():
-    """A transient failure with retries budget: second try at the SAME
-    rung succeeds — no demotion recorded."""
-    build, dims, blocks = PROGRAMS["layernorm_matmul"]
-    plan = RZ.FaultPlan([RZ.FaultSpec(site="compile:grouped",
-                                      indices=(0,))])
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+@pytest.mark.parametrize("max_rung", ["grouped", "ungrouped"])
+def test_bounded_max_rung_exhaustion_raises_ladder_error(name, max_rung):
+    """Every rung down to ``max_rung`` fails once: the compile raises
+    with one attempt per rung and serves nothing.  ``grouped`` is the
+    benchmark's own policy: a grouped failure is not demoted past."""
+    build, dims, blocks = PROGRAMS[name]
+    rungs = RZ.rungs_from("grouped", max_rung)
+    plan = RZ.FaultPlan([RZ.FaultSpec(site=f"compile:{r}") for r in rungs])
     opts = pipeline.CompileOptions(
         backend="pallas", blocks=blocks,
-        resilience=RZ.ResiliencePolicy(retries=1, backoff_s=0.0))
-    with RZ.faults(plan):
-        kern = pipeline.compile(build(), dims, options=opts,
-                                cache=_mem_cache())
-    rr = kern.resilience_report
-    assert rr.rung == "grouped" and rr.demotions == 0
-    assert [(a.ok, a.retry) for a in rr.attempts] == [(False, 0),
-                                                      (True, 1)]
-
-
-def test_slow_compile_times_out_and_demotes():
-    build, dims, blocks = PROGRAMS["layernorm_matmul"]
-    plan = RZ.FaultPlan([RZ.FaultSpec(site="compile:grouped",
-                                      kind="sleep", sleep_s=5.0)])
-    opts = pipeline.CompileOptions(
-        backend="pallas", blocks=blocks,
-        resilience=RZ.ResiliencePolicy(attempt_timeout_s=0.2))
-    with RZ.faults(plan), pytest.warns(RuntimeWarning,
-                                       match="compile ladder"):
-        kern = pipeline.compile(build(), dims, options=opts,
-                                cache=_mem_cache())
-    rr = kern.resilience_report
-    assert rr.attempts[0].timed_out and not rr.attempts[0].ok
-    assert rr.rung == "ungrouped"
-
-
-def test_bounded_max_rung_exhaustion_raises_ladder_error():
-    build, dims, blocks = PROGRAMS["layernorm_matmul"]
-    plan = RZ.FaultPlan([RZ.FaultSpec(site="compile:grouped"),
-                         RZ.FaultSpec(site="compile:ungrouped")])
-    opts = pipeline.CompileOptions(
-        backend="pallas", blocks=blocks,
-        resilience=RZ.ResiliencePolicy(max_rung="ungrouped"))
+        resilience=RZ.ResiliencePolicy(max_rung=max_rung))
     before = RZ.METRICS.snapshot()
-    with RZ.faults(plan), pytest.warns(RuntimeWarning), \
-            pytest.raises(RZ.LadderError) as ei:
-        pipeline.compile(build(), dims, options=opts, cache=_mem_cache())
+    cache = _mem_cache()
+    with RZ.faults(plan), pytest.raises(RZ.LadderError) as ei:
+        pipeline.compile(build(), dims, options=opts, cache=cache)
     rep = ei.value.report
-    assert [a.rung for a in rep.attempts] == ["grouped", "ungrouped"]
-    assert RZ.METRICS.delta(before).ladder_failures == 1
+    assert [a.rung for a in rep.attempts] == list(rungs)
+    assert not any(a.ok for a in rep.attempts) and rep.rung is None
+    assert all("InjectedFault" in e for e in rep.errors)
+    d = RZ.METRICS.delta(before)
+    assert d.ladder_failures == 1
+    assert d.demotions == len(rungs) - 1
+    # nothing was cached for the failed compile: the next one, with the
+    # faults spent, lowers at the grouped rung
+    assert pipeline.compile(build(), dims, options=opts,
+                            cache=cache).rung == "grouped"
 
 
 def test_config_errors_raise_instead_of_demoting():
@@ -230,14 +216,6 @@ def test_env_var_activates_plan(monkeypatch):
     assert RZ.active() is plan  # cached per env value: counters survive
     with pytest.raises(RZ.InjectedFault):
         RZ.check("compile:grouped")
-
-
-def test_run_with_timeout_does_not_block_on_hung_worker():
-    import time as _t
-    t0 = _t.perf_counter()
-    with pytest.raises(RZ.AttemptTimeout):
-        RZ.run_with_timeout(lambda: _t.sleep(10), 0.1)
-    assert _t.perf_counter() - t0 < 5.0  # returned without joining
 
 
 # ---------------------------------------------------------------------------
@@ -395,11 +373,37 @@ def test_injected_cache_corruption_drives_real_machinery(fresh_cache):
 # ---------------------------------------------------------------------------
 
 def _tiny_cfg(backend="jax", **overrides):
+    """A tiny smollm; ``backend="xla"`` keeps the plain XLA kernels, the
+    bottom rung of the serving ladder."""
     mc = configs.get_reduced_config(
         "smollm-135m", n_layers=2, d_model=64, n_heads=2, n_kv_heads=2,
         d_head=32, d_ff=128, vocab=128, **overrides)
+    if backend == "xla":
+        return mc
     return configs.with_pipeline(
         mc, options=pipeline.CompileOptions(backend=backend))
+
+
+def _plant_decode(engine, index: int, fault: str) -> None:
+    """Warm ``engine`` up, then wrap its ``_decode`` so the ``index``-th
+    call of the run loop (0-based) raises (``fault="raise"``) or returns
+    a NaN logits row for the first active slot (``fault="nan"``)."""
+    import jax.numpy as jnp
+
+    engine.warmup()
+    decode, calls = engine._decode, []
+
+    def planted(params, caches, tokens, pos):
+        calls.append(1)
+        if len(calls) - 1 == index and fault == "raise":
+            raise RuntimeError("planted decode fault")
+        logits, caches = decode(params, caches, tokens, pos)
+        if len(calls) - 1 == index and fault == "nan":
+            row = next(i for i, s in enumerate(engine.slots) if s)
+            logits = logits.at[row, -1].set(jnp.nan)
+        return logits, caches
+
+    engine._decode = planted
 
 
 def _oracle(engine, req):
@@ -422,19 +426,19 @@ def _oracle(engine, req):
     return toks
 
 
-def test_poison_request_evicted_cobatched_match_oracle(fresh_cache):
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_poison_request_evicted_cobatched_match_oracle(fresh_cache,
+                                                       backend):
     """The isolation acceptance: one NaN-logits request is evicted with
     a structured failure record while every co-batched sequence's tokens
     exactly match the sequential-decode oracle."""
     from repro.launch.engine import Engine, synth_trace
-    engine = Engine(_tiny_cfg("jax"), max_batch=3, max_len=48,
+    engine = Engine(_tiny_cfg(backend), max_batch=3, max_len=48,
                     prompt_buckets=(8, 16), sampling="greedy", seed=0)
     trace = synth_trace(6, seed=3, arrival_rate=1.5, prompt_lens=(3, 14),
                         gen_lens=(3, 6), vocab=engine.cfg.vocab)
-    plan = RZ.FaultPlan([RZ.FaultSpec(site="serve:logits", indices=(1,),
-                                      kind="nan")])
-    with RZ.faults(plan):
-        report = engine.run(trace)
+    _plant_decode(engine, 1, "nan")
+    report = engine.run(trace)
     assert report.n_poisoned == 1
     bad = [f for f in report.failures
            if f["reason"] == "nonfinite_logits"]
@@ -449,27 +453,36 @@ def test_poison_request_evicted_cobatched_match_oracle(fresh_cache):
             "eviction")
 
 
-def test_watchdog_demotes_decode_and_keeps_serving(fresh_cache):
+@pytest.mark.parametrize("start,demoted_to", [
+    ("pallas", "pipeline-jax"), ("jax", "xla"), ("xla", None)])
+def test_watchdog_demotes_decode_and_keeps_serving(fresh_cache, start,
+                                                   demoted_to):
     """A decode-step crash mid-run demotes the kernel one rung and the
     run completes; tokens still match the oracle on the ORIGINAL impl
-    (the demoted backend computes the same function)."""
+    (the demoted backend computes the same function).  On the bottom
+    rung there is nothing to demote to: the crash re-raises."""
     from repro.launch.engine import Engine, synth_trace
-    engine = Engine(_tiny_cfg("pallas"), max_batch=2, max_len=32,
+    engine = Engine(_tiny_cfg(start), max_batch=2, max_len=32,
                     prompt_buckets=(8,), sampling="greedy", seed=0)
-    oracle_engine = Engine(_tiny_cfg("pallas"), max_batch=2, max_len=32,
-                           prompt_buckets=(8,), sampling="greedy", seed=0)
     trace = synth_trace(4, seed=1, arrival_rate=1.0, prompt_lens=(2, 7),
                         gen_lens=(3, 5), vocab=engine.cfg.vocab)
-    plan = RZ.FaultPlan([RZ.FaultSpec(site="serve:decode", indices=(1,))])
-    with RZ.faults(plan), pytest.warns(RuntimeWarning,
-                                       match="serve watchdog"):
+    _plant_decode(engine, 1, "raise")
+    if demoted_to is None:
+        with pytest.raises(RuntimeError, match="planted decode fault"):
+            engine.run(trace)
+        assert engine.watchdog_demotions == 0
+        return
+    oracle_engine = Engine(_tiny_cfg(start), max_batch=2, max_len=32,
+                           prompt_buckets=(8,), sampling="greedy", seed=0)
+    with pytest.warns(RuntimeWarning, match="serve watchdog"):
         report = engine.run(trace)
     assert report.n_completed == len(trace)
     assert engine.watchdog_demotions == 1
     assert report.degradations >= 1
+    assert report.decode_backend == demoted_to
     demos = [f for f in report.failures
              if f["reason"] == "decode_demotion"]
-    assert len(demos) == 1 and demos[0]["to"] == "pipeline-jax"
+    assert len(demos) == 1 and demos[0]["to"] == demoted_to
     # strict_no_recompile stayed armed: the demotion compiles were
     # explained, and nothing else compiled
     assert report.decode_recompiles == 0
@@ -527,6 +540,60 @@ def test_clean_serve_run_has_zero_resilience_counters(fresh_cache):
     assert report.n_poisoned == 0
     assert report.n_deadline_evicted == 0
     assert report.failures == []
-    # the new counters serialize with the report
+    assert report.decode_backend == "pipeline-jax"
+    # the counters serialize with the report, and only these
     d = json.loads(json.dumps(report.to_json()))
     assert d["degradations"] == 0 and d["failures"] == []
+    assert {"n_poisoned", "n_deadline_evicted", "degradations",
+            "quarantined", "decode_backend"} <= set(d)
+    assert not {"repromotions", "probes", "probe_failures"} & set(d)
+
+
+def test_engine_accepts_only_none_for_repromote_after(fresh_cache):
+    from repro.launch.engine import Engine
+    with pytest.raises(ValueError, match="repromote_after"):
+        Engine(_tiny_cfg("jax"), repromote_after=8)
+    assert Engine(_tiny_cfg("jax"), repromote_after=None).cfg is not None
+
+
+# ---------------------------------------------------------------------------
+# no failure leaves state behind for the next process
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("where", ["compile", "serve"])
+def test_failure_leaves_no_state_for_the_next_process(fresh_cache, where):
+    """A faulted compile, or a watchdog demotion mid-serve, on a disk
+    cache writes nothing under ``<root>/health/``; a fresh cache or
+    engine on the same root starts at the top rung again."""
+    root = fresh_cache
+    if where == "compile":
+        build, dims, blocks = PROGRAMS["layernorm_matmul"]
+        plan = RZ.FaultPlan([RZ.FaultSpec(site="compile:grouped")])
+        with RZ.faults(plan), pytest.warns(RuntimeWarning,
+                                           match="compile ladder"):
+            kern = pipeline.compile(build(), dims, backend="pallas",
+                                    blocks=blocks,
+                                    cache=C.KernelCache(root=root))
+        assert kern.rung == "ungrouped"
+        assert not (root / "health").exists()
+        again = pipeline.compile(build(), dims, backend="pallas",
+                                 blocks=blocks,
+                                 cache=C.KernelCache(root=root))
+        assert again.rung == "grouped"
+    else:
+        from repro.launch.engine import Engine, synth_trace
+        engine = Engine(_tiny_cfg("pallas"), max_batch=2, max_len=32,
+                        prompt_buckets=(8,), sampling="greedy", seed=0)
+        trace = synth_trace(3, seed=1, arrival_rate=1.0,
+                            prompt_lens=(2, 7), gen_lens=(3, 4),
+                            vocab=engine.cfg.vocab)
+        _plant_decode(engine, 1, "raise")
+        with pytest.warns(RuntimeWarning, match="serve watchdog"):
+            report = engine.run(trace)
+        assert report.decode_backend == "pipeline-jax"
+        assert not (root / "health").exists()
+        pipeline.reset_default_cache()
+        fresh = Engine(_tiny_cfg("pallas"), max_batch=2, max_len=32,
+                       prompt_buckets=(8,), sampling="greedy", seed=0)
+        assert fresh.run(trace).decode_backend == "pipeline-pallas"
+    assert not (root / "health").exists()
